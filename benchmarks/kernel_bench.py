@@ -132,7 +132,7 @@ def _argsort_send_layout(num_dest: int, capacity: int):
 _COLLECTIVES_CODE = """
 import jax, numpy as np, jax.numpy as jnp, dataclasses, json
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.introspect import collective_counts
 from repro.core.shuffle import ShufflePlan
 
@@ -205,6 +205,9 @@ def collectives_per_hop() -> Dict[str, object]:
     """jaxpr-count all_to_all per shuffle hop on 8 virtual devices (own
     subprocess: XLA_FLAGS must be set before jax initializes)."""
     env = dict(os.environ)
+    # semantics on 8 virtual CPU devices: the child never needs the chip,
+    # which a parent that has touched JAX may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
@@ -395,7 +398,7 @@ def run(csv: bool = True, json_path: str | None = None) -> List[str]:
         f"(all_to_all per hop; was 4 flat / 9 hier / 7 / 15 with combine)")
 
     if json_path:
-        from repro.kernels.ops import _interpret_default
+        from repro.kernels.autotune import interpret_default
         # other benches (streaming, chaos, obs) merge their trajectory
         # points into the same file, each stamped with an "owner" field —
         # keep every row another owner wrote, rewrite only our own.
@@ -412,7 +415,7 @@ def run(csv: bool = True, json_path: str | None = None) -> List[str]:
         payload = {
             "schema": "repro.kernel_bench.v1",
             "backend": jax.default_backend(),
-            "pallas_interpret": _interpret_default(),
+            "pallas_interpret": interpret_default(),
             "note": ("CPU container: Pallas rows run in interpret mode; "
                      "jnp/XLA rows are compiled. The trajectory point is "
                      "partition_speedup_vs_argsort (fused O(n) send path "

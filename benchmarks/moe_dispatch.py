@@ -54,6 +54,9 @@ print(f"RESULT moe_dense_coll_bytes {sum(c2.values())} {c2}")
 
 def run(csv: bool = True) -> List[str]:
     env = dict(os.environ)
+    # semantics on 8 virtual CPU devices: the child never needs the chip,
+    # which a parent that has touched JAX may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

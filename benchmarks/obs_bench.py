@@ -159,6 +159,9 @@ print("RESULT " + json.dumps(out))
 
 def bench(trace_path: str) -> Dict[str, object]:
     env = dict(os.environ)
+    # semantics on 8 virtual CPU devices: the child never needs the chip,
+    # which a parent that has touched JAX may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

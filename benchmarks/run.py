@@ -12,6 +12,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (kernel_bench, moe_dispatch, obs_bench, roofline,
                             scalability, sdss_distribution, storage_modes,
                             streaming_bench, terasort, wan_shuffle)

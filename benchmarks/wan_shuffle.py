@@ -150,7 +150,7 @@ def model_wan_round(
 _MEASURE_CODE = """
 import time, numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.shuffle import ShufflePlan, sphere_shuffle
 mesh1 = jax.make_mesh((8,), ("data",))
 mesh2 = jax.make_mesh((2, 4), ("dc", "node"))
@@ -201,6 +201,9 @@ print(f"RESULT hier {t_hier * 1e6:.1f} wan_tiles={ph['wan_tiles']} "
 
 def measured_8dev() -> List[str]:
     env = dict(os.environ)
+    # semantics on 8 virtual CPU devices: the child never needs the chip,
+    # which a parent that has touched JAX may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -20,7 +20,8 @@ Run:  PYTHONPATH=src python examples/streaming_wordcount.py
 
 import _bootstrap
 
-_bootstrap.setup(devices=8)
+if __name__ == "__main__":      # importers (chip_smoke.py) bring their devices
+    _bootstrap.setup(devices=8)
 
 import collections
 

@@ -45,9 +45,11 @@ def main() -> None:
 
         for name, fn in (
             ("sphere (pallas stage-2)",
-             lambda ex=SPMDExecutor(mesh, use_pallas=True): run_df(ex)),
+             lambda ex=SPMDExecutor(
+                 mesh, use_pallas=True, sort_algo="bitonic"): run_df(ex)),
             ("sphere (xla sort)",
-             lambda ex=SPMDExecutor(mesh, use_pallas=False): run_df(ex)),
+             lambda ex=SPMDExecutor(
+                 mesh, sort_algo="oracle"): run_df(ex)),
             ("hadoop-style (allgather)",
              lambda: hadoop_style_sort(kd, pd, mesh)),
         ):

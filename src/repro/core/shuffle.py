@@ -83,7 +83,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.records import WireFrame
 from repro.kernels import ops as kops
 
@@ -273,7 +272,7 @@ def sphere_shuffle(
         pack/exchange rounds of capacity ``ceil(capacity / W)`` each.
       wire_meta: which metadata to ship per record (see WIRE_META_MODES).
     """
-    axis_size = compat.axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     if num_buckets % axis_size != 0:
         raise ValueError(f"num_buckets={num_buckets} not divisible by "
                          f"axis size {axis_size}")
@@ -334,8 +333,8 @@ def hierarchical_shuffle(
       chunks / wire_meta: as for :func:`sphere_shuffle` (both stages chunk;
         stage A always carries the bucket — stage B routes by it).
     """
-    dcs = compat.axis_size(dc_axis)
-    nodes = compat.axis_size(node_axis)
+    dcs = jax.lax.axis_size(dc_axis)
+    nodes = jax.lax.axis_size(node_axis)
     num_devices = dcs * nodes
     if num_buckets % num_devices != 0:
         raise ValueError(f"num_buckets={num_buckets} not divisible by "

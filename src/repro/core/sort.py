@@ -38,7 +38,7 @@ from typing import Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core.shuffle import ShufflePlan
 from repro.kernels import ops as kops
@@ -113,11 +113,10 @@ def terasort(
     ``axis``/``buckets_per_device``/``capacity_factor``: its axes and bucket
     count drive the sharding specs and splitters. ``sort_algo`` pins the
     stage-2 segment-sort kernel (``"bitonic"``/``"radix"``/``"oracle"``);
-    ``None`` defers to the legacy ``use_pallas`` switch (``True`` → the
-    bitonic kernel, ``False`` → the backend-aware autotuner of
-    :mod:`repro.kernels.autotune`), independently of ``plan.use_pallas``
-    (which governs the shuffle histogram) — the kernel-vs-oracle parity
-    benchmark relies on switching them separately. ``chunks`` sets the
+    ``None`` defers to the backend-aware autotuner of
+    :mod:`repro.kernels.autotune`. ``use_pallas`` runs the partition/pack
+    send path and the stage-2 regroup through the Pallas rank kernel, as
+    on :class:`~repro.sphere.dataflow.SPMDExecutor`. ``chunks`` sets the
     shuffle pipeline depth:
     W interleaved pack/exchange rounds per hop (see
     :func:`repro.core.shuffle.sphere_shuffle`); ``None`` defers to

@@ -27,7 +27,7 @@ from typing import Callable, Sequence, Union
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core.stream import SphereStream
 

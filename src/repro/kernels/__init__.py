@@ -7,17 +7,18 @@ TPU there is no efficient per-element scatter, so we adapt:
 
 - ``bucket_hist``   — per-tile one-hot histogram, computed as an MXU matmul
                       (int32 accumulation).
-- ``partition``     — fused histogram + stable counting rank in one pass:
+- ``partition``     — fused histogram + stable counting rank in one pass
+                      (the running count is an MXU matmul):
                       the O(n) shuffle send path (replaces the stable argsort
                       every send used to pay).
-- ``bitonic_sort``  — in-VMEM bitonic network over (key, payload) pairs using
-                      XOR-partner compare-exchange realized as reshapes/flips
-                      (no gather/scatter), the TPU-native sort; one grid step
+- ``bitonic_sort``  — in-VMEM bitonic network over (key, payload) pairs;
+                      XOR partners arrive by lane rotations (``pltpu.roll``,
+                      no gather/scatter), the TPU-native sort; one grid step
                       sorts a sublane-packed block of segments. Not stable.
-- ``radix_sort``    — stable LSD counting-radix sort: per-byte one-hot
-                      cumsum rank (the ``partition`` primitive, one pass per
-                      key digit) with the permutation applied as chunked
-                      one-hot MXU matmuls — no gather/scatter at all.
+- ``radix_sort``    — stable LSD counting-radix sort: one-hot digit rank as
+                      chunked MXU matmuls (the ``partition`` primitive, one
+                      pass per key digit) and the permutation as one-hot MXU
+                      matmuls — no gather/scatter at all.
 - ``autotune``      — backend-aware dispatch: measures bitonic vs radix vs
                       the XLA oracle once per segment-geometry cell, caches
                       the winner, persists the table into BENCH_kernels.json.

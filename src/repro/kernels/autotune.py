@@ -30,9 +30,11 @@ Resolution order (first hit wins):
    static default ``"oracle"`` — measurement noise beats kernel differences
    on tiny segments, and the stable oracle is always correct,
 5. measure all eligible candidates, pick the fastest. Candidates outside
-   their envelope (radix beyond its VMEM bound, or interpret-mode radix past
-   the measurement budget) are skipped **with a recorded reason** — never
-   silently.
+   their envelope (bitonic or radix beyond its VMEM bound, or
+   interpret-mode radix past the measurement budget) are skipped **with a
+   recorded reason** — never silently. A candidate inside its envelope that
+   fails to compile or run raises: a kernel the device's compiler refuses
+   is a fault to fix, never a reason to fall back to the XLA oracle.
 
 Every choice is stable-aware: callers that need stability (the stage-2
 segmented sort's suffix padding) ask :func:`is_stable` about the resolved
@@ -53,10 +55,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.kernels.bitonic_sort import (sort_kv_segments_pallas,
-                                        sort_segments_pallas)
-from repro.kernels.radix_sort import (radix_supported, sort_kv_segments_radix,
-                                      sort_segments_radix)
 
 ALGOS = ("bitonic", "radix", "oracle")
 
@@ -106,13 +104,26 @@ _table_loaded = False
 _cell_key_memo: Dict[Tuple, str] = {}
 
 
+INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
+
+
 def interpret_default() -> bool:
-    """Pallas interpret mode: forced by ``REPRO_PALLAS_INTERPRET``, else on
-    exactly when the backend is CPU (no Mosaic compiler)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() == "cpu"
+    """Pallas interpret mode for every kernel entry point: on exactly when
+    the backend is CPU (no Mosaic compiler). ``REPRO_PALLAS_INTERPRET=0``
+    forces compiled kernels (compiling for a described, unattached TPU from
+    a CPU host); ``=1`` is refused on any backend but the CPU, so a device
+    run can never quietly execute the interpreter."""
+    backend = jax.default_backend()
+    env = os.environ.get(INTERPRET_ENV)
+    if env is None:
+        return backend == "cpu"
+    interpret = env not in ("0", "false", "False")
+    if interpret and backend != "cpu":
+        raise RuntimeError(
+            f"{INTERPRET_ENV}={env!r} on the {backend!r} backend: the Pallas "
+            f"interpreter is for CPU hosts only; unset it to run the "
+            f"compiled kernels")
+    return interpret
 
 
 def cell_key(num_segments: int, segment_len: int, dtype, kv: bool,
@@ -206,6 +217,11 @@ def _synth(num_segments: int, segment_len: int, dtype, kv: bool):
 
 
 def _candidate(algo: str, kv: bool, interpret: bool) -> Callable:
+    # kernel modules import this one (interpret_default), so import lazily
+    from repro.kernels.bitonic_sort import (sort_kv_segments_pallas,
+                                            sort_segments_pallas)
+    from repro.kernels.radix_sort import (sort_kv_segments_radix,
+                                          sort_segments_radix)
     if algo == "oracle":
         fn = ref.sort_kv_segments_ref if kv else ref.sort_segments_ref
         return jax.jit(fn)
@@ -230,6 +246,32 @@ def _time(fn: Callable, args) -> float:
     return best
 
 
+def _envelope(algo: str, num_segments: int, segment_len: int, dtype,
+              interpret: bool) -> Optional[str]:
+    """Why ``algo`` cannot run this cell, or None. These are the only
+    reasons a candidate is skipped; any other failure propagates."""
+    from repro.kernels.bitonic_sort import bitonic_supported
+    from repro.kernels.radix_sort import radix_supported
+    if algo == "bitonic":
+        return bitonic_supported(segment_len)
+    if algo != "radix":
+        return None
+    reason = radix_supported(segment_len)
+    if reason is None and jnp.dtype(dtype) not in (
+            jnp.int32, jnp.uint32, jnp.float32):
+        reason = f"unsupported key dtype {jnp.dtype(dtype).name}"
+    n_elem = num_segments * segment_len
+    if reason is None and interpret and (
+            segment_len > _RADIX_MEASURE_MAX_SEGLEN
+            or n_elem > _RADIX_MEASURE_MAX_ELEMS):
+        reason = (f"interpret-mode measurement budget: "
+                  f"{num_segments}x{segment_len} exceeds "
+                  f"{_RADIX_MEASURE_MAX_ELEMS} elems / "
+                  f"{_RADIX_MEASURE_MAX_SEGLEN} seg-len "
+                  f"(matmul permutation is emulated on CPU)")
+    return reason
+
+
 def _measure(num_segments: int, segment_len: int, dtype, kv: bool,
              interpret: bool) -> Choice:
     args = _synth(num_segments, segment_len, dtype, kv)
@@ -237,30 +279,12 @@ def _measure(num_segments: int, segment_len: int, dtype, kv: bool,
     melem: Dict[str, float] = {}
     skipped: Dict[str, str] = {}
     for algo in ALGOS:
-        if algo == "radix":
-            reason = radix_supported(segment_len)
-            if reason is None and jnp.dtype(dtype) not in (
-                    jnp.int32, jnp.uint32, jnp.float32):
-                reason = f"unsupported key dtype {jnp.dtype(dtype).name}"
-            if reason is None and interpret and (
-                    segment_len > _RADIX_MEASURE_MAX_SEGLEN
-                    or n_elem > _RADIX_MEASURE_MAX_ELEMS):
-                reason = (f"interpret-mode measurement budget: "
-                          f"{num_segments}x{segment_len} exceeds "
-                          f"{_RADIX_MEASURE_MAX_ELEMS} elems / "
-                          f"{_RADIX_MEASURE_MAX_SEGLEN} seg-len "
-                          f"(matmul permutation is emulated on CPU)")
-            if reason is not None:
-                skipped[algo] = reason
-                continue
-        try:
-            dt = _time(_candidate(algo, kv, interpret), args)
-        except Exception as e:  # candidate failed outright: disqualify
-            skipped[algo] = f"{type(e).__name__}: {e}"
+        reason = _envelope(algo, num_segments, segment_len, dtype, interpret)
+        if reason is not None:
+            skipped[algo] = reason
             continue
-        melem[algo] = n_elem / dt / 1e6
-    if not melem:
-        return Choice("oracle", "static", melem={}, skipped=skipped)
+        melem[algo] = n_elem / _time(_candidate(algo, kv, interpret),
+                                     args) / 1e6
     best = max(melem, key=lambda a: melem[a])
     return Choice(best, "measured", melem=melem, skipped=skipped)
 

@@ -5,23 +5,26 @@ call a CPU quicksort on the whole segment. Quicksort is branch/scatter bound
 and has no TPU analogue, so we adapt the insight ("sort whole segments where
 they live") to the TPU's vector units with a **bitonic sorting network**:
 
-- compare-exchange partners at distance ``j`` (a power of two) are obtained
-  by viewing each segment row as ``(S//(2j), 2, j)`` and splitting the
-  middle axis — XOR-partner addressing with *no gather/scatter*;
-- the ascending/descending direction of stage ``k`` depends only on the
-  block index ``q``, so it is a broadcasted-iota predicate;
+- the compare-exchange partner of element ``i`` at distance ``j`` (a power
+  of two) is ``i ^ j``: two lane rotations (``pltpu.roll`` by ``j`` and by
+  ``S - j``) and a select on bit ``j`` of the lane index bring every
+  partner into place with *no gather/scatter* and no relayout;
+- the ascending/descending direction of stage ``k`` depends only on bit
+  ``k`` of the lane index, so it is a broadcasted-iota predicate;
 - the whole network is O(S log² S) fully-vectorized compare-exchanges on
-  segments resident in VMEM.
+  segments resident in VMEM; the distances of one merge level run as a
+  ``fori_loop`` with a dynamic rotation, so the program stays small
+  however long the segment is.
 
 One grid step sorts a **block of segments** at once: the operands stay 2-D
 ``(rows, S)`` — segments along the sublane axis, elements along the lane
 axis — and every compare-exchange is a sublane×lane-shaped select over all
-rows of the block simultaneously. (The original kernel flattened one row to
-1-D per grid step and rebuilt it with ``stack``/``reshape`` relayouts each
-stage; the 2-D form keeps the lane dimension intact for ``j >= lane`` and
-amortizes one grid step over ``rows`` segments — the multi-segment layout
-the segmented terasort stage 2 feeds it, where sorting ``bpd`` rows of
-``R/bpd`` cuts the network from O(R log² R) to O(R log² (R/bpd)).)
+rows of the block simultaneously, amortizing one grid step over ``rows``
+segments (the multi-segment layout the segmented terasort stage 2 feeds
+it, where sorting ``bpd`` rows of ``R/bpd`` cuts the network from
+O(R log² R) to O(R log² (R/bpd))). Segments are padded to at least one
+lane tile (128) and to a power of two; :func:`bitonic_supported` states the
+VMEM envelope.
 
 The payload array is permuted alongside the keys (used to carry record
 indices through the sort).
@@ -30,54 +33,71 @@ indices through the sort).
 from __future__ import annotations
 
 import functools
-import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.autotune import interpret_default
 
-def _compare_exchange(keys, vals, k_exp: int, j_exp: int):
-    """One bitonic stage over a (rows, S) block: partners at distance
-    2^j_exp within blocks of 2^k_exp, for every row at once."""
-    r, s = keys.shape
-    j = 1 << j_exp
-    half = s // (2 * j)
-    ks = keys.reshape(r, half, 2, j)
-    vs = vals.reshape(r, half, 2, j)
-    lo_k, hi_k = ks[:, :, 0, :], ks[:, :, 1, :]
-    lo_v, hi_v = vs[:, :, 0, :], vs[:, :, 1, :]
-    # ascending iff bit k_exp of the element index is 0; that bit lives at
-    # bit (k_exp - j_exp - 1) of the block index q.
-    shift = k_exp - j_exp - 1
-    q = jax.lax.broadcasted_iota(jnp.int32, (1, half, 1), 1)
-    dir_up = ((q >> shift) & 1) == 0
-    swap = jnp.where(dir_up, lo_k > hi_k, lo_k < hi_k)
-    new_lo_k = jnp.where(swap, hi_k, lo_k)
-    new_hi_k = jnp.where(swap, lo_k, hi_k)
-    new_lo_v = jnp.where(swap, hi_v, lo_v)
-    new_hi_v = jnp.where(swap, lo_v, hi_v)
-    keys = jnp.concatenate([new_lo_k[:, :, None, :], new_hi_k[:, :, None, :]],
-                           axis=2).reshape(r, s)
-    vals = jnp.concatenate([new_lo_v[:, :, None, :], new_hi_v[:, :, None, :]],
-                           axis=2).reshape(r, s)
-    return keys, vals
+#: VMEM budget (bytes) for one (rows, S) int32 operand block: the network
+#: keeps keys, values, both rotations of each and the masks live, and
+#: Pallas double-buffers the four in/out blocks.
+_BLOCK_BUDGET = 1 << 20
+
+_LANES = 128
 
 
 def _bitonic_kernel(keys_ref, vals_ref, out_k_ref, out_v_ref):
-    s = keys_ref.shape[-1]
-    m = int(math.log2(s))
+    rows, s = keys_ref.shape
+    m = s.bit_length() - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, s), 1)
     keys = keys_ref[...]                    # (rows, S): one block of segments
     vals = vals_ref[...]
     for k_exp in range(1, m + 1):
-        for j_exp in range(k_exp - 1, -1, -1):
-            keys, vals = _compare_exchange(keys, vals, k_exp, j_exp)
+        # 0 where the element's block sorts ascending (bit k_exp clear)
+        down = (lane >> k_exp) & 1
+
+        def stage(t, kv, down=down, k_exp=k_exp):
+            keys, vals = kv
+            j = jax.lax.shift_left(jnp.int32(1), k_exp - 1 - t)
+            # 1 where the element is the upper of its pair: partner i ^ j
+            # sits at i - j (roll by j), else at i + j (roll by s - j)
+            upper = jnp.where((lane & j) == 0, 0, 1)
+            pk = jnp.where(upper == 0, pltpu.roll(keys, s - j, 1),
+                           pltpu.roll(keys, j, 1))
+            pv = jnp.where(upper == 0, pltpu.roll(vals, s - j, 1),
+                           pltpu.roll(vals, j, 1))
+            # the lower slot of an ascending pair (and the upper slot of a
+            # descending one) keeps the minimum
+            take_min = upper == down
+            swap = ((take_min & (pk < keys))
+                    | (jnp.logical_not(take_min) & (pk > keys)))
+            return jnp.where(swap, pk, keys), jnp.where(swap, pv, vals)
+
+        keys, vals = jax.lax.fori_loop(0, k_exp, stage, (keys, vals))
     out_k_ref[...] = keys
     out_v_ref[...] = vals
 
 
-def _next_pow2(x: int) -> int:
-    return 1 << max(1, (x - 1).bit_length())
+def _segment_pad(s: int) -> int:
+    """Padded segment length: a power of two, at least one lane tile."""
+    return max(_LANES, 1 << max(0, (s - 1).bit_length()))
+
+
+def bitonic_supported(segment_len: int, rows_per_step: int = 8
+                      ) -> Optional[str]:
+    """None when one ``(rows_per_step, S)`` block fits the kernel's VMEM
+    budget, else the reason (callers record it — never a silent skip)."""
+    s_pad = _segment_pad(segment_len)
+    nbytes = rows_per_step * s_pad * 4
+    if nbytes > _BLOCK_BUDGET:
+        return (f"bitonic block rows·S_pad·4 = {nbytes} bytes exceeds the "
+                f"{_BLOCK_BUDGET}-byte VMEM budget (S={segment_len} padded "
+                f"to {s_pad}, {rows_per_step} rows per step)")
+    return None
 
 
 def _max_sentinel(dtype):
@@ -86,41 +106,40 @@ def _max_sentinel(dtype):
     return jnp.array(jnp.iinfo(dtype).max, dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rows_per_step", "interpret"))
 def sort_kv_segments_pallas(keys: jnp.ndarray, values: jnp.ndarray,
                             rows_per_step: int = 8,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """Sort each row of ``keys`` ascending, permuting ``values`` alongside.
 
     keys/values: (num_segments, segment_len). Each grid step sorts
     ``rows_per_step`` segments at once (sublane-packed). Padding — segment
-    length to the next power of two, segment count to a whole number of
-    blocks — uses a max sentinel so padded slots sort to the end and are
-    sliced off. Not stable — callers needing stability pack a unique
-    tiebreak into keys.
+    length to a power of two of at least 128, segment count to a whole
+    number of blocks — uses a max sentinel so padded slots sort to the end
+    and are sliced off. Not stable — callers needing stability pack a
+    unique tiebreak into keys. ``interpret=None`` resolves through
+    :func:`repro.kernels.autotune.interpret_default`.
     """
+    if interpret is None:
+        interpret = interpret_default()
+    return _sort_kv(keys, values, rows_per_step, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("rows_per_step", "interpret"))
+def _sort_kv(keys, values, rows_per_step: int, interpret: bool):
     n, s = keys.shape
-    s_pad = _next_pow2(s)
-    if s_pad != s:
-        pad_k = jnp.full((n, s_pad - s), _max_sentinel(keys.dtype), keys.dtype)
-        pad_v = jnp.zeros((n, s_pad - s), values.dtype)
-        keys = jnp.concatenate([keys, pad_k], axis=1)
-        values = jnp.concatenate([values, pad_v], axis=1)
-    rb = max(1, min(rows_per_step, n))
+    s_pad = _segment_pad(s)
+    rb = n if n <= rows_per_step else rows_per_step
     n_pad = -(-n // rb) * rb
-    if n_pad != n:
-        pad_k = jnp.full((n_pad - n, s_pad), _max_sentinel(keys.dtype),
-                         keys.dtype)
-        pad_v = jnp.zeros((n_pad - n, s_pad), values.dtype)
-        keys = jnp.concatenate([keys, pad_k], axis=0)
-        values = jnp.concatenate([values, pad_v], axis=0)
+    sentinel = _max_sentinel(keys.dtype)
+    keys = jnp.pad(keys, ((0, n_pad - n), (0, s_pad - s)),
+                   constant_values=sentinel)
+    values = jnp.pad(values, ((0, n_pad - n), (0, s_pad - s)))
+    spec = pl.BlockSpec((rb, s_pad), lambda i: (i, 0))
     out_k, out_v = pl.pallas_call(
         _bitonic_kernel,
         grid=(n_pad // rb,),
-        in_specs=[pl.BlockSpec((rb, s_pad), lambda i: (i, 0)),
-                  pl.BlockSpec((rb, s_pad), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((rb, s_pad), lambda i: (i, 0)),
-                   pl.BlockSpec((rb, s_pad), lambda i: (i, 0))],
+        in_specs=[spec, spec],
+        out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((n_pad, s_pad), keys.dtype),
                    jax.ShapeDtypeStruct((n_pad, s_pad), values.dtype)],
         interpret=interpret,
@@ -128,8 +147,8 @@ def sort_kv_segments_pallas(keys: jnp.ndarray, values: jnp.ndarray,
     return out_k[:n, :s], out_v[:n, :s]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def sort_segments_pallas(keys: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def sort_segments_pallas(keys: jnp.ndarray,
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """Keys-only row sort (payload = dummy)."""
     dummy = jnp.zeros_like(keys, dtype=jnp.int32)
     out_k, _ = sort_kv_segments_pallas(keys, dummy, interpret=interpret)

@@ -5,12 +5,15 @@ destined for every range bucket so the shuffle can lay records out
 contiguously per destination. The CPU version is a table increment per
 record; on TPU the idiomatic form is::
 
-    counts = ones(1, T) @ one_hot(ids, B)      # an MXU matmul per tile
+    counts = one_hot(ids, B)ᵀ @ ones(T, 128)    # an MXU matmul per tile
 
 Grid iterates over tiles of the id vector; all grid steps map to the *same*
 output block, which Pallas keeps resident in VMEM and we accumulate into
-(initialized at step 0). Bucket ids outside [0, B) contribute nothing — the
-wrapper uses that to pad inputs to a whole number of tiles.
+(initialized at step 0). Buckets sit on sublanes and records on lanes, so
+the one-hot needs no relayout of the id vector; every lane of the
+``(B, 128)`` result holds the same count. Bucket ids outside [0, B)
+contribute nothing — the wrapper uses that to pad inputs to a whole number
+of tiles.
 
 The per-tile matmul runs in float32 (exact: a tile holds at most ``tile`` <
 2^24 records), but the running accumulator is **int32** — a float32
@@ -21,28 +24,29 @@ accumulator silently loses +1 increments once a bucket's count passes 2^24
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.autotune import interpret_default
 
-def _hist_kernel(ids_ref, out_ref, *, num_buckets: int):
-    step = pl.program_id(0)
 
-    @pl.when(step == 0)
+def _hist_kernel(ids_ref, out_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     ids = ids_ref[...]  # (1, tile) int32
     tile = ids.shape[-1]
-    # one-hot over the (padded) bucket axis; 2D iota is TPU-safe.
-    buckets = jax.lax.broadcasted_iota(jnp.int32, (tile, num_buckets), 1)
-    onehot = (ids.reshape(tile, 1) == buckets).astype(jnp.float32)
-    ones = jnp.ones((1, tile), dtype=jnp.float32)
-    # MXU matmul: (1, tile) @ (tile, B) -> (1, B); per-tile counts <= tile
-    # < 2^24 so the f32 matmul is exact — accumulate in int32 (exact to 2^31)
-    counts = jnp.dot(ones, onehot, preferred_element_type=jnp.float32)
+    buckets = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[0], tile), 0)
+    onehot = jnp.where(buckets == ids, 1.0, 0.0).astype(jnp.bfloat16)
+    ones = jnp.ones((tile, out_ref.shape[1]), jnp.bfloat16)
+    # MXU matmul: (B, tile) @ (tile, 128) -> (B, 128); per-tile counts <=
+    # tile < 2^24 so the f32 matmul is exact — accumulate in int32 (exact
+    # to 2^31)
+    counts = jnp.dot(onehot, ones, preferred_element_type=jnp.float32)
     out_ref[...] += counts.astype(jnp.int32)
 
 
@@ -50,27 +54,36 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-@functools.partial(jax.jit, static_argnames=("num_buckets", "tile", "interpret"))
 def bucket_histogram_pallas(
     bucket_ids: jnp.ndarray,
     num_buckets: int,
     tile: int = 2048,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """int32 (num_buckets,) histogram of ``bucket_ids`` (int32 (n,))."""
+    """int32 (num_buckets,) histogram of ``bucket_ids`` (int32 (n,)).
+    ``interpret=None`` resolves through
+    :func:`repro.kernels.autotune.interpret_default`."""
+    if interpret is None:
+        interpret = interpret_default()
+    return _bucket_histogram(bucket_ids, num_buckets, tile, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("num_buckets", "tile",
+                                             "interpret"))
+def _bucket_histogram(bucket_ids, num_buckets: int, tile: int,
+                      interpret: bool):
     n = bucket_ids.shape[0]
     n_pad = max(_round_up(n, tile), tile)
-    # pad with an id guaranteed out of range -> lands in no bucket column
-    ids = jnp.full((n_pad,), num_buckets, dtype=jnp.int32).at[:n].set(
-        bucket_ids.astype(jnp.int32))
-    b_pad = _round_up(max(num_buckets, 1), 128)  # lane-aligned bucket axis
-    grid = (n_pad // tile,)
+    # pad with an id guaranteed out of range -> lands in no bucket row
+    ids = jnp.pad(bucket_ids.astype(jnp.int32), (0, n_pad - n),
+                  constant_values=-1)
+    b_pad = _round_up(max(num_buckets, 1), 16)
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, num_buckets=b_pad),
-        grid=grid,
+        _hist_kernel,
+        grid=(n_pad // tile,),
         in_specs=[pl.BlockSpec((1, tile), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, b_pad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, b_pad), jnp.int32),
+        out_specs=pl.BlockSpec((b_pad, 128), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b_pad, 128), jnp.int32),
         interpret=interpret,
     )(ids.reshape(1, n_pad))
-    return out[0, :num_buckets]
+    return out[:num_buckets, 0]

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On the CPU container the kernels execute in Pallas ``interpret`` mode (the
-kernel body runs as traced JAX ops); on a real TPU set
-``REPRO_PALLAS_INTERPRET=0`` to run the compiled kernels.
+Every kernel picks its mode through
+:func:`repro.kernels.autotune.interpret_default`: compiled by Mosaic on a
+TPU, Pallas ``interpret`` mode (the kernel body runs as traced JAX ops) on
+a CPU host.
 
 The segment-sort entry points (:func:`sort_segments`,
 :func:`sort_kv_segments`) dispatch through the backend-aware autotuner
@@ -32,9 +33,6 @@ from repro.kernels.radix_sort import (sort_kv_segments_radix,
                                       sort_segments_radix)
 
 _UNSET = object()  # sentinel: "use_pallas not passed" (deprecation shim)
-
-_interpret_default = autotune.interpret_default
-
 
 def pad_sentinel(dtype):
     """Greatest value of ``dtype`` — the padding key that sorts to the end
@@ -83,8 +81,7 @@ def bucket_histogram(bucket_ids: jnp.ndarray, num_buckets: int,
     """int32 (num_buckets,) histogram; ids outside range are ignored."""
     if not use_pallas:
         return ref.bucket_histogram_ref(bucket_ids, num_buckets)
-    return bucket_histogram_pallas(bucket_ids, num_buckets,
-                                   interpret=_interpret_default())
+    return bucket_histogram_pallas(bucket_ids, num_buckets)
 
 
 def partition_rank(dest: jnp.ndarray, num_dest: int,
@@ -93,8 +90,7 @@ def partition_rank(dest: jnp.ndarray, num_dest: int,
     see :func:`repro.kernels.partition.partition_rank_pallas`."""
     if not use_pallas:
         return ref.partition_rank_ref(dest, num_dest)
-    return partition_rank_pallas(dest, num_dest,
-                                 interpret=_interpret_default())
+    return partition_rank_pallas(dest, num_dest)
 
 
 def partition_pack(
@@ -168,8 +164,8 @@ def sort_segments(keys: jnp.ndarray, use_pallas=_UNSET, *,
     if resolved == "oracle":
         return ref.sort_segments_ref(keys)
     if resolved == "radix":
-        return sort_segments_radix(keys, interpret=_interpret_default())
-    return sort_segments_pallas(keys, interpret=_interpret_default())
+        return sort_segments_radix(keys)
+    return sort_segments_pallas(keys)
 
 
 def sort_kv_segments(keys: jnp.ndarray, values: jnp.ndarray,
@@ -187,7 +183,5 @@ def sort_kv_segments(keys: jnp.ndarray, values: jnp.ndarray,
     if resolved == "oracle":
         return ref.sort_kv_segments_ref(keys, values)
     if resolved == "radix":
-        return sort_kv_segments_radix(keys, values,
-                                      interpret=_interpret_default())
-    return sort_kv_segments_pallas(keys, values,
-                                   interpret=_interpret_default())
+        return sort_kv_segments_radix(keys, values)
+    return sort_kv_segments_pallas(keys, values)

@@ -48,7 +48,7 @@ def partition_rank_ref(dest: jnp.ndarray, num_dest: int, tile: int = 4096):
         return ids, jnp.zeros((num_dest,), jnp.int32)
     tile = min(tile, n)
     n_pad = (n + tile - 1) // tile * tile
-    padded = jnp.full((n_pad,), -1, jnp.int32).at[:n].set(ids)
+    padded = jnp.pad(ids, (0, n_pad - n), constant_values=-1)
     cols = jnp.arange(num_dest, dtype=jnp.int32)[None, :]
 
     def step(base, chunk):

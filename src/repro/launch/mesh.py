@@ -8,14 +8,17 @@ module constants, so importing never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -23,7 +26,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"need {data * model} devices, have {n}")
-    return make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def dp_axes_of(mesh) -> tuple:

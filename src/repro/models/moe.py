@@ -22,7 +22,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.core.shuffle import ShufflePlan

@@ -22,9 +22,9 @@ The same pipeline object runs on two executors with identical results:
   program: maps/reduces inline per device, shuffles become capacity-bounded
   ``all_to_all`` via :class:`repro.core.shuffle.ShufflePlan` (flat or
   two-level wide-area, all sends through the fused O(n) partition/pack),
-  sort stage 2 regroups bucket-major and runs the multi-segment Pallas
-  bitonic kernel. Compiled programs are cached keyed on (pipeline, plan,
-  input shapes/dtypes).
+  sort stage 2 regroups bucket-major and runs one autotuned multi-segment
+  sort (Pallas bitonic or radix kernel, or XLA's sort). Compiled programs
+  are cached keyed on (pipeline, plan, input shapes/dtypes).
 - :class:`HostExecutor` lowers the same graph onto
   :class:`repro.sphere.engine.SphereProcess` / SPEs over Sector-stored
   files: maps run at the SPEs with locality scheduling and retry, shuffle
@@ -68,7 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.introspect import collective_counts
 from repro.core.records import RecordCodec
 from repro.core.shuffle import ShufflePlan, record_hops
@@ -287,6 +287,20 @@ CacheInfo = namedtuple("CacheInfo",
                        ["hits", "misses", "evictions", "currsize", "maxsize"])
 
 
+class SortCell(NamedTuple):
+    """Stage-2 geometry of a sort stage (see :meth:`SPMDExecutor.sort_cell`)."""
+    recv: int                 # receive-buffer slots per device
+    buckets_per_device: int   # segments per device
+    segment: int              # slots per segment
+
+
+def _segment_capacity(recv: int, bpd: int, capacity_factor: float) -> int:
+    """Slots per stage-2 segment: ``capacity_factor`` headroom over the
+    uniform share of the receive buffer (the whole buffer for one bucket)."""
+    return recv if bpd == 1 else min(recv, int(recv / bpd * capacity_factor)
+                                     + 1)
+
+
 class _CacheEntry(NamedTuple):
     """One compiled program. ``fn`` is the AOT-compiled executable
     (``jit(...).lower(args).compile()`` — compile cost is paid exactly once
@@ -326,13 +340,14 @@ class SPMDExecutor:
     same pipeline object on same-shaped data costs zero retracing while
     long-lived executors cannot accumulate compiled programs without bound.
 
-    ``sort_algo`` pins the stage-2 segment-sort kernel (``"bitonic"`` /
-    ``"radix"`` / ``"oracle"``); ``None`` defers to the backend-aware
-    autotuner (:mod:`repro.kernels.autotune`) — measured once per segment
-    geometry, replayed from cache afterwards — except that the legacy
-    ``use_pallas=True`` keeps its historical meaning and pins
-    ``"bitonic"``. ``REPRO_KERNEL_FORCE`` overrides everything (and is part
-    of the compile-cache key, so flipping it between runs retraces).
+    ``use_pallas`` runs every shuffle's partition/pack send path (and the
+    stage-2 regroup) through the Pallas rank kernel instead of its jnp
+    oracle. ``sort_algo`` pins the stage-2 segment-sort kernel
+    (``"bitonic"`` / ``"radix"`` / ``"oracle"``); ``None`` defers to the
+    backend-aware autotuner (:mod:`repro.kernels.autotune`) — measured once
+    per segment geometry, replayed from cache afterwards.
+    ``REPRO_KERNEL_FORCE`` overrides everything (and is part of the
+    compile-cache key, so flipping it between runs retraces).
 
     ``debug_checks`` (on by default) validates, after each run of a
     pipeline containing a sort, that no real record key collided with the
@@ -356,8 +371,7 @@ class SPMDExecutor:
         self.axes = tuple(plan.axes) if plan is not None else tuple(
             (axes,) if isinstance(axes, str) else axes)
         self.use_pallas = use_pallas
-        self.sort_algo = (sort_algo if sort_algo is not None
-                          else ("bitonic" if use_pallas else None))
+        self.sort_algo = sort_algo
         self.chunks = chunks
         self.cache_size = cache_size
         self.debug_checks = debug_checks
@@ -516,7 +530,7 @@ class SPMDExecutor:
         REGISTRY.counter("spmd.cache.misses").inc()
         hops: List[dict] = []
         with tr.span("spmd.lower", pipeline=pipeline.describe()):
-            jitted = self._lower(pipeline, with_carry=with_carry)
+            jitted = self.program(pipeline, with_carry=with_carry)
             with record_hops(hops):
                 lowered = jitted.lower(*args)
         with tr.span("spmd.compile"):
@@ -714,7 +728,13 @@ class SPMDExecutor:
                               trace=tr if tr.enabled else None)
 
     # -- lowering -------------------------------------------------------------
-    def _lower(self, df: Dataflow, with_carry: bool = False) -> Callable:
+    def program(self, df: Dataflow, with_carry: bool = False) -> Callable:
+        """The fused program of ``df``: one jitted ``shard_map`` over the
+        mesh taking ``(records, valid)`` (plus the carry pair when
+        ``with_carry``) and returning ``(records, valid, dropped,
+        sentinel_hits[, carry...])``. :meth:`run` compiles and caches it;
+        ``program(df).lower(...)`` is its ahead-of-time entry, and it is
+        what :func:`repro.core.introspect.collective_counts` inspects."""
         spec = P(self.axes[0]) if len(self.axes) == 1 else P(self.axes)
         axes = self.axes
         carry_at = _last_reduce_index(df) if with_carry else -1
@@ -786,6 +806,19 @@ class SPMDExecutor:
         mapped = shard_map(local, mesh=self.mesh, in_specs=(spec, spec),
                            out_specs=(spec, spec, P(), P()), check_vma=False)
         return jax.jit(mapped)
+
+    def sort_cell(self, stage: SortStage, n_local: int) -> SortCell:
+        """The stage-2 geometry ``stage`` sorts in when ``n_local``
+        records per device enter it: the receive buffer's length and the
+        ``buckets_per_device`` x ``segment`` cell the segment sort (and the
+        autotuner's cell key) sees."""
+        nb = (self.plan.num_buckets if self.plan is not None
+              else stage.num_buckets or self.axis_size)
+        plan = self._stage_plan(nb, n_local, stage.capacity_factor,
+                                stage.chunks)
+        recv, bpd = plan.recv_slots, plan.buckets_per_device
+        return SortCell(recv, bpd,
+                        _segment_capacity(recv, bpd, stage.capacity_factor))
 
     def _stage_plan(self, num_buckets: Optional[int], n_local: int,
                     capacity_factor: float,
@@ -869,8 +902,7 @@ class SPMDExecutor:
         skey = jnp.where(valid, keys, sentinel)
         r = skey.shape[0]
         bpd = plan.buckets_per_device
-        seg_cap = (r if bpd == 1 else
-                   min(r, int(r / bpd * stage.capacity_factor) + 1))
+        seg_cap = _segment_capacity(r, bpd, stage.capacity_factor)
         # resolve the stage-2 kernel now (trace-time): stability decides
         # whether sentinel-collision accounting is needed at all.
         algo = kops.resolve_sort_algo(bpd, seg_cap, skey.dtype,

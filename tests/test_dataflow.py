@@ -279,7 +279,7 @@ df = Dataflow.source(codec).sort(key=lambda r: r["key"], num_buckets=8)
 
 mesh = jax.make_mesh((8,), ("data",))
 with mesh:
-    sres = SPMDExecutor(mesh, use_pallas=True).run(
+    sres = SPMDExecutor(mesh, use_pallas=True, sort_algo="bitonic").run(
         df, {"key": jnp.asarray(keys), "payload": jnp.asarray(payload)})
 svr = sres.valid_records()
 assert int(sres.dropped) == 0

@@ -19,7 +19,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 PRELUDE = """
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.shuffle import ShufflePlan, sphere_shuffle
 mesh1 = jax.make_mesh((8,), ("data",))
 mesh2 = jax.make_mesh((2, 4), ("dc", "node"))
@@ -151,7 +151,8 @@ payload = np.arange(N, dtype=np.int32)
 kd = jax.device_put(jnp.asarray(keys), NamedSharding(mesh2, P(("dc", "node"))))
 pd = jax.device_put(jnp.asarray(payload), NamedSharding(mesh2, P(("dc", "node"))))
 with mesh2:
-    res = terasort(kd, pd, mesh2, axis=("dc", "node"), use_pallas=True)
+    res = terasort(kd, pd, mesh2, axis=("dc", "node"), use_pallas=True,
+                   sort_algo="bitonic")
 assert int(res.dropped) == 0
 assert is_globally_sorted(res, 8)
 vk = np.asarray(res.keys)[np.asarray(res.valid)]

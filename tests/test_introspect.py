@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.introspect import (COLLECTIVE_PRIMITIVES, collective_counts,
                                    primitive_counts)
 from repro.core.shuffle import ShufflePlan

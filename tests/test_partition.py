@@ -183,6 +183,36 @@ def test_property_kernel_equals_oracle(dests, capacity):
         _check_equal(dest, cols, 6, capacity, up)
 
 
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_rank_inside_shard_map_on_default_explicit_mesh(use_pallas):
+    """Regression: jax.make_mesh makes Explicit axes by default, and the
+    jnp oracle's ``.at[:n].set`` padding failed inside an eager shard_map
+    on such a mesh ("Unexpected XLA sharding override"). Four devices,
+    because a one-device mesh never shows it."""
+    from test_spmd import run_spmd
+    run_spmd(f"""
+import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+from repro.kernels import ops, ref
+mesh = jax.make_mesh((4,), ("data",))
+assert mesh.axis_types == (AxisType.Explicit,)
+dest = np.random.default_rng(0).integers(0, 5, size=4 * 300).astype(np.int32)
+dd = jax.device_put(jnp.asarray(dest), NamedSharding(mesh, P("data")))
+f = shard_map(lambda d: ops.partition_rank(d, 4, use_pallas={use_pallas}),
+              mesh=mesh, in_specs=P("data"), out_specs=(P("data"), P("data")),
+              check_vma=False)
+with mesh:
+    rank, counts = f(dd)
+rank, counts = np.asarray(rank), np.asarray(counts).reshape(4, 4)
+for s, shard in enumerate(dest.reshape(4, -1)):
+    want_rank, want_counts = ref.partition_rank_ref(jnp.asarray(shard), 4)
+    ok = shard < 4
+    assert (rank.reshape(4, -1)[s][ok] == np.asarray(want_rank)[ok]).all()
+    assert (counts[s] == np.asarray(want_counts)).all()
+""", devices=4)
+
+
 # -- segmented stage-2 sort ----------------------------------------------------
 
 

@@ -41,7 +41,7 @@ payload = np.arange(N, dtype=np.int32)
 kd = jax.device_put(jnp.asarray(keys), NamedSharding(mesh, P("data")))
 pd = jax.device_put(jnp.asarray(payload), NamedSharding(mesh, P("data")))
 with mesh:
-    res = terasort(kd, pd, mesh, use_pallas=True)
+    res = terasort(kd, pd, mesh, use_pallas=True, sort_algo="bitonic")
 assert int(res.dropped) == 0
 assert is_globally_sorted(res, 8)
 vk = np.asarray(res.keys)[np.asarray(res.valid)]
@@ -64,10 +64,10 @@ keys = rng.integers(0, 2**31 - 2, size=N).astype(np.int32)
 payload = np.arange(N, dtype=np.int32)
 kd = jax.device_put(jnp.asarray(keys), NamedSharding(mesh, P("data")))
 pd = jax.device_put(jnp.asarray(payload), NamedSharding(mesh, P("data")))
-for use_pallas in (True, False):
+for use_pallas, sort_algo in ((True, "bitonic"), (False, None)):
     with mesh:
         res = terasort(kd, pd, mesh, use_pallas=use_pallas,
-                       buckets_per_device=4)
+                       buckets_per_device=4, sort_algo=sort_algo)
     assert int(res.dropped) == 0
     assert is_globally_sorted(res, 8)
     vk = np.asarray(res.keys)[np.asarray(res.valid)]
@@ -101,7 +101,7 @@ assert (ka == kc).all()        # use_pallas is honored, not dead
 def test_sphere_shuffle_invariants():
     run_spmd(PRELUDE + """
 from repro.core.shuffle import sphere_shuffle
-from repro.compat import shard_map
+from jax import shard_map
 N = 8 * 512
 data = rng.integers(0, 1000, size=(N, 3)).astype(np.int32)
 buckets = rng.integers(0, 16, size=N).astype(np.int32)

@@ -139,3 +139,38 @@ def test_train_checkpoint_restart_continuity(deployment):
         p2, o2, metrics = step(p2, o2, jb)
         assert float(metrics["loss"]) == pytest.approx(
             ref_losses[10 + i], rel=1e-5), i
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.cache import CHECKOUT_DIR, enable_compile_cache
+used = enable_compile_cache()
+assert jax.config.jax_compilation_cache_dir == used, used
+print(used)
+if used != str(CHECKOUT_DIR):     # never write into the checkout here
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.jit(lambda x: x * 2 + 1)(jnp.arange(7)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_location(env_dir, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and receives the cache; without it
+    the entry points use the checkout's fixed .jax_cache directory."""
+    import os
+    import subprocess
+    import sys
+    from repro.launch.cache import CHECKOUT_DIR
+
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(CHECKOUT_DIR.parent / "src")
+    want = str(CHECKOUT_DIR)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = want = str(tmp_path / "cache")
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == want
+    if env_dir:
+        assert any((tmp_path / "cache").iterdir())

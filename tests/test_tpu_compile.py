@@ -1,0 +1,111 @@
+"""Compile rehearsals for TPU v5e: the main path, compiled by the TPU
+compiler for a described (not attached) ``v5e:2x2`` topology.
+
+Nothing here runs on a chip. The compiles catch what interpret mode cannot:
+a kernel Mosaic refuses, a block that overflows VMEM, a program that does
+not fit the chip's memory, a collective the partitioner added. The kernels
+compile at the shapes ``chip_smoke.py`` runs (its one-chip terasort cell);
+the four-chip programs at a smaller per-chip count (see
+``FOUR_CHIP_TEST_N``).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every pytest worker imports this
+file.
+"""
+
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+import chip_smoke  # noqa: E402
+from repro.kernels.bitonic_sort import sort_kv_segments_pallas  # noqa: E402
+from repro.kernels.partition import partition_rank_pallas  # noqa: E402
+from repro.kernels.radix_sort import sort_kv_segments_radix  # noqa: E402
+
+N_PER_CHIP = 1 << chip_smoke.MIN_LOG2_N
+#: per-chip records of the four-chip program compiles. Their host memory
+#: and time grow with it (~23 GB and ~5 min at chip_smoke's 2^21), so the
+#: rehearsal checks the structure at a size a test worker can afford.
+FOUR_CHIP_TEST_N = 1 << 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else the compiler logs to /tmp
+        try:
+            described = topologies.get_topology_desc(platform="tpu",
+                                                     topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu, or it cannot describe v5e here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield described
+
+
+@pytest.fixture(scope="module")
+def one_chip_terasort(topo):
+    """The one-chip terasort deployment chip_smoke.py sizes first."""
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    return chip_smoke.Terasort(mesh, ("data",), N_PER_CHIP)
+
+
+def _kernel_case(name, ts, chip):
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if name == "partition_send":
+        return (lambda d: partition_rank_pallas(d, ts.mesh.size,
+                                                interpret=False),
+                (arg((ts.n_per_device,)),))
+    if name == "partition_regroup":
+        return (lambda d: partition_rank_pallas(d, ts.bpd, interpret=False),
+                (arg((ts.recv,)),))
+    kernel = {"bitonic": sort_kv_segments_pallas,
+              "radix": sort_kv_segments_radix}[name]
+    shape = (ts.bpd, ts.seg_cap)
+    return (lambda k, v: kernel(k, v, interpret=False),
+            (arg(shape), arg(shape)))
+
+
+@pytest.mark.parametrize("name", ["partition_send", "partition_regroup",
+                                  "bitonic", "radix"])
+def test_kernel_compiles_at_terasort_cell(name, topo, one_chip_terasort):
+    fn, args = _kernel_case(name, one_chip_terasort,
+                            SingleDeviceSharding(topo.devices[0]))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert total <= chip_smoke.MEMORY_LIMIT
+
+
+@pytest.mark.parametrize("layout", ["flat", "hierarchical"])
+def test_terasort_program_compiles_on_four_chips(layout, topo, monkeypatch):
+    """The fused SPMDExecutor terasort on the four described chips: Pallas
+    kernels inside, one all_to_all per flat hop and two per hierarchical
+    hop, every chip within the memory bound."""
+    # default_backend() is the CPU here; the program is compiled for the TPU
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    devices = np.array(topo.devices)
+    if layout == "flat":
+        ts = chip_smoke.Terasort(Mesh(devices, ("data",)), ("data",),
+                                 FOUR_CHIP_TEST_N, sort_algo="bitonic")
+    else:
+        ts = chip_smoke.Terasort(Mesh(devices.reshape(2, 2), ("dc", "node")),
+                                 ("dc", "node"), FOUR_CHIP_TEST_N,
+                                 hierarchical=True, sort_algo="bitonic")
+    compiled, _, mem = ts.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert mem["total"] <= chip_smoke.MEMORY_LIMIT
+    want = 2 if layout == "hierarchical" else 1
+    assert ts.all_to_all() == want
+    assert "all-to-all" in text

@@ -165,7 +165,7 @@ PRELUDE = """
 import dataclasses
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.shuffle import ShufflePlan
 from repro.kernels import ops as kops
 mesh1 = jax.make_mesh((8,), ("data",))
