@@ -8,8 +8,6 @@ initializes) runs the whole instrumented surface:
   untraced vs traced runs on the warm compile cache in interleaved pairs —
   ``obs_overhead`` is the median traced/untraced ratio and ``--check``
   gates it below :data:`OVERHEAD_BOUND`.
-- **Staged trace** (``trace_stages=True``): one compiled program per
-  stage → per-stage ``hop[i]:sort`` rows for BENCH_kernels.json.
 - **Host terasort** over a real in-process Sector deployment, with a
   ``drop_bucket`` fault injected so the retry AND mid-job recovery series
   show up in the metrics snapshot.
@@ -19,9 +17,11 @@ initializes) runs the whole instrumented surface:
 All three executors share ONE trace buffer (``tracer.fork``), so the
 Perfetto file written to ``--trace PATH`` shows them as side-by-side
 threads; CI uploads it as a workflow artifact every run. ``--check``
-additionally validates the trace_event JSON (nested stage→hop spans on
-both executor tracks) and that every required metric series is present in
-the registry snapshot.
+additionally validates the trace_event JSON (``spmd.execute`` nested in
+``spmd.run`` on the SPMD track, phase→segment spans on the host track) and
+that every required metric series is present in the registry snapshot.
+Per-stage device time of the fused program comes from a ``jax.profiler``
+trace and its op->layer map (docs/OBSERVABILITY.md), not from this bench.
 """
 
 from __future__ import annotations
@@ -87,17 +87,6 @@ with mesh:
         r = ex.run(df, data, trace=tracer)   # blocks internally (fencing)
         t_tr.append(time.perf_counter() - t0)
     overhead = float(np.median(np.asarray(t_tr) / np.asarray(t_un)))
-    # staged mode: one compiled program per stage -> per-stage spans
-    sres = ex.run(df, data, trace=tracer, trace_stages=True)
-    assert (np.diff(sres.valid_records()["key"]) >= 0).all()
-stage_rows = [
-    {"name": s.name, "ms": (s.end - s.start) * 1e3,
-     "attrs": {k: v for k, v in s.attrs.items()
-               if k in ("records", "dropped", "wire_bytes_per_device",
-                        "chunks")}}
-    for s in tracer.buffer.spans()
-    if s.track == "spmd"
-    and (s.name.startswith("hop[") or s.name.startswith("stage["))]
 
 # -- host executor: same sort over a real Sector deployment ------------------
 htr = tracer.fork("host")
@@ -147,7 +136,6 @@ out = {
     "overhead": overhead, "iters": iters, "n_records": N,
     "untraced_us": float(np.median(t_un) * 1e6),
     "traced_us": float(np.median(t_tr) * 1e6),
-    "stage_rows": stage_rows,
     "phase_times": hres.phase_times,
     "host_retries": hres.retries, "host_recoveries": hres.recoveries,
     "stream_batches": len(batches),
@@ -203,12 +191,13 @@ def check_trace(trace_path: str) -> List[str]:
     for want in ("spmd", "host", "stream"):
         if want not in tracks:
             failures.append(f"missing {want!r} track in trace")
-    # SPMD: the staged root span must contain per-stage hop spans
-    staged = [e for e in xs if e["name"] == "spmd.run.staged"]
-    if not staged:
-        failures.append("no spmd.run.staged span")
-    elif not _contained(xs, staged[0], "hop["):
-        failures.append("no hop[i] span nested inside spmd.run.staged")
+    # SPMD: the root span must contain the execute span
+    runs = [e for e in xs if e["tid"] == tracks.get("spmd")
+            and e["name"] == "spmd.run"]
+    if not runs:
+        failures.append("no spmd.run span")
+    elif not _contained(xs, runs[0], "spmd.execute"):
+        failures.append("no spmd.execute span nested inside spmd.run")
     # host: each phase span must contain segment spans; phase 1 (bucket
     # sort) follows the hop[0]:buckets materialization span
     phases = [e for e in xs if e["tid"] == tracks.get("host")
@@ -247,8 +236,6 @@ def check(res: Dict[str, object], trace_path: str) -> List[str]:
             break
     if int(res["host_recoveries"]) < 1:
         failures.append("drop_bucket fault produced no recovery")
-    if not res["stage_rows"]:
-        failures.append("staged trace produced no per-stage rows")
     failures.extend(check_trace(trace_path))
     return failures
 
@@ -270,7 +257,6 @@ def _merge_json(json_path: str, res: Dict[str, object]) -> None:
     }
     payload["results"]["obs_stage_times"] = {
         "owner": OWNER,
-        "stages": res["stage_rows"],
         "host_phases": res["phase_times"],
     }
     with open(json_path, "w") as f:
@@ -284,8 +270,7 @@ def run(csv: bool = True, json_path: str | None = None,
         f"obs_overhead,{res['traced_us']:.0f},"
         f"ratio={res['overhead']:.3f}x (bound {OVERHEAD_BOUND:.2f}x) "
         f"untraced={res['untraced_us']:.0f}us over {res['iters']} pairs",
-        f"obs_trace,{len(res['stage_rows'])},"
-        f"stage rows; perfetto written to {trace_path}",
+        f"obs_trace,0,perfetto written to {trace_path}",
         f"obs_host_phases,{len(res['phase_times'])},"
         f"retries={res['host_retries']} recoveries={res['host_recoveries']}",
     ]

@@ -125,6 +125,11 @@ def partition_pack(
       origin:    (num_dest, capacity) int32 source row (-1 on empty slots;
                  meaningful only where ``in_range``)
       dropped_local: () int32 — records beyond capacity, this shard only.
+
+    The rank pass runs in the named scope ``partition.rank``, the scatter
+    and gathers in ``partition.move``, so a profile of any program that
+    calls this can tell them apart (Sphere counts them in its partition/
+    pack layer: :mod:`repro.obs.layers`).
     """
     dest = jnp.asarray(dest, jnp.int32).reshape(-1)
     n = dest.shape[0]
@@ -134,19 +139,22 @@ def partition_pack(
         return (tiles, jnp.zeros((num_dest, capacity), bool),
                 jnp.full((num_dest, capacity), -1, jnp.int32),
                 jnp.zeros((), jnp.int32))
-    rank, counts = partition_rank(dest, num_dest, use_pallas=use_pallas)
-    ok = (dest >= 0) & (dest < num_dest) & (rank < capacity)
-    slot = jnp.where(ok, dest * capacity + rank, num_dest * capacity)
-    origin = (jnp.full((num_dest * capacity + 1,), -1, jnp.int32)
-              .at[slot].set(jnp.arange(n, dtype=jnp.int32))
-              [:num_dest * capacity].reshape(num_dest, capacity))
-    cap_iota = jnp.arange(capacity, dtype=counts.dtype)[None, :]
-    in_range = cap_iota < counts[:, None]
-    gidx = jnp.clip(origin, 0, n - 1).reshape(-1)
-    tiles = [jnp.take(col, gidx, axis=0)
-             .reshape((num_dest, capacity) + col.shape[1:])
-             for col in columns]
-    dropped_local = jnp.sum(jnp.maximum(counts - capacity, 0)).astype(jnp.int32)
+    with jax.named_scope("partition.rank"):
+        rank, counts = partition_rank(dest, num_dest, use_pallas=use_pallas)
+    with jax.named_scope("partition.move"):
+        ok = (dest >= 0) & (dest < num_dest) & (rank < capacity)
+        slot = jnp.where(ok, dest * capacity + rank, num_dest * capacity)
+        origin = (jnp.full((num_dest * capacity + 1,), -1, jnp.int32)
+                  .at[slot].set(jnp.arange(n, dtype=jnp.int32))
+                  [:num_dest * capacity].reshape(num_dest, capacity))
+        cap_iota = jnp.arange(capacity, dtype=counts.dtype)[None, :]
+        in_range = cap_iota < counts[:, None]
+        gidx = jnp.clip(origin, 0, n - 1).reshape(-1)
+        tiles = [jnp.take(col, gidx, axis=0)
+                 .reshape((num_dest, capacity) + col.shape[1:])
+                 for col in columns]
+        dropped_local = jnp.sum(jnp.maximum(counts - capacity, 0)
+                                ).astype(jnp.int32)
     return tiles, in_range, origin, dropped_local
 
 

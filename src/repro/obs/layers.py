@@ -1,0 +1,65 @@
+"""Layer scopes of the fused SPMD program, and the op->layer map of the
+program that ran last.
+
+Each layer of :class:`repro.sphere.dataflow.SPMDExecutor`'s fused program
+wraps its work in one ``jax.named_scope``. Scopes change only the metadata
+of the compiled program: XLA carries the name stack into every optimised
+instruction's ``metadata={op_name="jit(...)/.../sphere.<layer>/..."}``,
+and the innermost scope of :data:`SCOPES` on that path names the op's
+layer (:func:`repro.core.introspect.layer_of`).
+
+- ``sphere.bucket``: range bucketing, the splitters and both
+  ``searchsorted`` calls of a sort;
+- ``sphere.rank``: ``kernels.ops.partition_rank`` (Pallas kernel or jnp
+  oracle);
+- ``sphere.move``: row moves, partition/pack's slot-map scatter and
+  gathers, ``RecordCodec.pack``/``unpack``, the sort's final gather;
+- ``sphere.exchange``: the shuffle hop, framing, ``WireFrame.seal``/
+  ``open`` and the ``all_to_all``;
+- ``sphere.segsort``: the stage-2 segment sort and the sentinel keys that
+  feed it;
+- ``sphere.udf``: map, reduce and shuffle-key UDF calls and the streaming
+  carry.
+
+``kernels.ops.partition_pack`` also serves programs that are not Sphere's
+(expert dispatch), so it names its two passes in its own terms,
+``partition.rank`` and ``partition.move``; :data:`SCOPES` counts them in
+Sphere's layers.
+
+Every run of the executor notes the executable's HLO module name and its
+map ``{instruction name: layer}``, read once when it was compiled, so a
+reader of a ``jax.profiler`` trace (whose op events are named by their
+instructions) can sum device time per layer without reading HLO by hand.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional, Tuple
+
+BUCKET = "sphere.bucket"
+RANK = "sphere.rank"
+MOVE = "sphere.move"
+EXCHANGE = "sphere.exchange"
+SEGSORT = "sphere.segsort"
+UDF = "sphere.udf"
+
+#: every scope the program names, with the layer it counts in.
+SCOPES: Dict[str, str] = {
+    BUCKET: BUCKET, RANK: RANK, MOVE: MOVE, EXCHANGE: EXCHANGE,
+    SEGSORT: SEGSORT, UDF: UDF,
+    "partition.rank": RANK, "partition.move": MOVE,
+}
+
+_last: Optional[Tuple[str, Dict[str, str]]] = None
+
+
+def ran(module: str, op_layers: Dict[str, str]) -> None:
+    """Note that the executable of ``module`` with this map ran last."""
+    global _last
+    _last = (module, op_layers)
+
+
+def last_run() -> Optional[Tuple[str, Dict[str, str]]]:
+    """``(module name, op->layer map)`` of the program that ran last, or
+    None before any run."""
+    return _last

@@ -18,6 +18,12 @@ stack. Design rules:
 - **Tracks.** ``tracer.fork("host")`` returns a tracer writing to the SAME
   buffer under a different track name — one Perfetto file can hold the SPMD
   and host executors side by side as separate threads.
+- **Spans reach the profiler.** Every span, of a :class:`Tracer` and of
+  :data:`NULL_TRACER` alike, also opens a ``jax.profiler.TraceAnnotation``
+  of its name (no attributes) for its duration. A ``jax.profiler`` trace of
+  the program then holds ``spmd.run``, ``spmd.execute`` or
+  ``stream.batch[k]`` on the host line, on the same clock as the device's
+  ops; with no profiler session active an annotation costs one check.
 
 Exports: :meth:`Tracer.to_perfetto` writes Chrome/Perfetto ``trace_event``
 JSON (open in https://ui.perfetto.dev or chrome://tracing);
@@ -32,6 +38,8 @@ import json
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "TraceBuffer", "Tracer", "NullTracer", "NULL_TRACER"]
 
@@ -242,17 +250,20 @@ class Tracer:
 
 class _SpanContext:
     """Context manager for one span: opens on ``__enter__``, pushes onto the
-    thread's stack, appends to the buffer on ``__exit__``."""
+    thread's stack, appends to the buffer on ``__exit__``; a profiler
+    annotation of the span's name runs alongside."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_span")
+    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_ann")
 
     def __init__(self, tracer: Tracer, name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
         self._span: Optional[Span] = None
+        self._ann = TraceAnnotation(name)
 
     def __enter__(self) -> Span:
+        self._ann.__enter__()
         tr = self._tracer
         stack = tr._stack()
         self._span = Span(
@@ -272,6 +283,7 @@ class _SpanContext:
         if exc_type is not None:
             sp.attrs.setdefault("error", f"{exc_type.__name__}: {exc}")
         tr.buffer.add_span(sp)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -285,29 +297,36 @@ class _NullSpan:
 
 
 class _NullContext:
-    __slots__ = ()
+    """A span that records nothing: only its profiler annotation."""
+
+    __slots__ = ("_ann",)
     _SPAN = _NullSpan()
 
+    def __init__(self, name: str):
+        self._ann = TraceAnnotation(name)
+
     def __enter__(self) -> _NullSpan:
+        self._ann.__enter__()
         return self._SPAN
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 class NullTracer:
-    """Do-nothing tracer: executors use it when no trace is requested so
-    the hot path has no branches beyond one attribute check. Falsy, so
-    ``trace or NULL_TRACER`` composes."""
+    """Tracer that records nothing: executors use it when no trace is
+    requested so the hot path has no branches beyond one attribute check.
+    Its spans still annotate a ``jax.profiler`` session, if one is active.
+    Falsy, so ``trace or NULL_TRACER`` composes."""
 
     enabled = False
-    _CTX = _NullContext()
 
     def __bool__(self) -> bool:
         return False
 
     def span(self, name: str, **attrs: Any) -> _NullContext:
-        return self._CTX
+        return _NullContext(name)
 
     def event(self, name: str, **attrs: Any) -> None:
         pass

@@ -69,11 +69,13 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from jax import shard_map
-from repro.core.introspect import collective_counts
+from repro.core.introspect import collective_counts, module_name, op_layers
 from repro.core.records import RecordCodec
 from repro.core.shuffle import ShufflePlan, record_hops
 from repro.kernels import autotune
 from repro.kernels import ops as kops
+from repro.obs import layers as obs_layers
+from repro.obs.layers import BUCKET, MOVE, EXCHANGE, SEGSORT, UDF
 from repro.obs.metrics import REGISTRY
 from repro.obs.trace import NULL_TRACER
 
@@ -308,13 +310,17 @@ class _CacheEntry(NamedTuple):
     static shuffle-hop geometry captured at lowering time via
     :func:`repro.core.shuffle.record_hops`; ``collectives`` the jaxpr
     collective counts (only computed when the entry was built under an
-    active tracer — the extra trace is not free)."""
+    active tracer — the extra trace is not free). ``module`` is the
+    executable's HLO module name and ``layers`` its op->layer map
+    (:func:`repro.core.introspect.op_layers`), read once per compile."""
 
     pipeline: "Dataflow"
     fn: Callable
     has_sort: bool
     hops: List[dict]
     collectives: Optional[Dict[str, int]]
+    module: str
+    layers: Dict[str, str]
 
 
 _STAGE_KIND = {MapStage: "map", ShuffleStage: "shuffle",
@@ -386,11 +392,9 @@ class SPMDExecutor:
         self._hits = 0
         self._misses = 0
         self._evictions = 0
-        self._last_entry: Optional[_CacheEntry] = None
-        # chaos/resume + staged-trace machinery: per-hop/per-stage
-        # sub-pipelines (pinning their parent so id()-keyed lookups stay
-        # sound) and sub-executors per mesh, so repeated runs reuse
-        # compiled sub-programs
+        # chaos/resume machinery: per-hop sub-pipelines (pinning their
+        # parent so id()-keyed lookups stay sound) and sub-executors per
+        # mesh, so repeated runs reuse compiled sub-programs
         self._subflows: Dict[Tuple, Tuple[Dataflow, Dataflow]] = {}
         self._sub_execs: Dict[Any, "SPMDExecutor"] = {}
 
@@ -409,8 +413,7 @@ class SPMDExecutor:
             valid: Optional[Any] = None,
             carry: Optional[Tuple[Any, Any]] = None,
             chaos: Optional[Any] = None,
-            trace: Optional[Any] = None,
-            trace_stages: bool = False) -> DataflowResult:
+            trace: Optional[Any] = None) -> DataflowResult:
         """Execute ``pipeline`` over ``records`` sharded along ``axes``.
 
         ``records``: pytree of global arrays (or a
@@ -441,12 +444,12 @@ class SPMDExecutor:
         covers real device time) with wire-byte, chunk-round and drop
         attributes, and publishes wire-bytes / collective / drop counters
         to the metrics registry. Untraced runs skip everything that would
-        force a device sync.
-
-        ``trace_stages``: with a tracer, run one compiled program per stage
-        instead of the fused program, so every stage and every shuffle/sort
-        hop gets its own span. A profiling mode — per-stage dispatch has
-        real overhead and is NOT held to the obs_bench <5% bound.
+        force a device sync. Traced or not, the spans ``spmd.run``,
+        ``spmd.execute`` and ``spmd.sentinel_check`` annotate a
+        ``jax.profiler`` session, and the program's ops carry their layer
+        scopes (:mod:`repro.obs.layers`): a profile attributes device time
+        to stages through the op->layer map of the program that ran
+        (``repro.obs.layers.last_run()``).
         """
         from repro.core.stream import SphereStream
         if isinstance(records, SphereStream):
@@ -456,11 +459,6 @@ class SPMDExecutor:
         if chaos is not None:
             return self._run_segmented(pipeline, records, valid, carry,
                                        chaos, tr)
-        if trace_stages and tr.enabled:
-            if carry is not None:
-                raise ValueError("trace_stages does not compose with "
-                                 "streaming carry state")
-            return self._run_staged(pipeline, records, valid, tr)
         records = jax.tree.map(jnp.asarray, records)
         n = _leading(records)
         if valid is None:
@@ -483,8 +481,9 @@ class SPMDExecutor:
                ckey)
         args = ((records, valid, carry[0], carry[1]) if carry is not None
                 else (records, valid))
-        with tr.span("spmd.run", pipeline=pipeline.describe(),
-                     records=n) as root:
+        attrs = ({"pipeline": pipeline.describe(), "records": n}
+                 if tr.enabled else {})
+        with tr.span("spmd.run", **attrs) as root:
             entry = self._cache.get(key)
             if entry is None:
                 root.set(cache="miss")
@@ -495,7 +494,7 @@ class SPMDExecutor:
                 REGISTRY.counter("spmd.cache.hits").inc()
                 self._cache.move_to_end(key)
                 root.set(cache="hit")
-            self._last_entry = entry
+            obs_layers.ran(entry.module, entry.layers)
             with tr.span("spmd.execute", hops=len(entry.hops)):
                 out = entry.fn(*args)
                 if tr.enabled:
@@ -508,9 +507,14 @@ class SPMDExecutor:
             else:
                 out_records, out_valid, dropped, sentinel_hits = out
                 out_carry = None
-            if self.debug_checks and entry.has_sort and int(sentinel_hits) > 0:
+            hits = 0
+            if self.debug_checks and entry.has_sort:
+                # one scalar sync a call: the host waits for the program
+                with tr.span("spmd.sentinel_check"):
+                    hits = int(sentinel_hits)
+            if hits > 0:
                 raise ValueError(
-                    f"{int(sentinel_hits)} record key(s) equal the key "
+                    f"{hits} record key(s) equal the key "
                     f"dtype's maximum — the stage-2 sort padding sentinel — "
                     f"while the unstable 'bitonic' kernel is selected: the "
                     f"network's tie order is unspecified, so they could "
@@ -529,12 +533,17 @@ class SPMDExecutor:
         self._misses += 1
         REGISTRY.counter("spmd.cache.misses").inc()
         hops: List[dict] = []
-        with tr.span("spmd.lower", pipeline=pipeline.describe()):
+        attrs = {"pipeline": pipeline.describe()} if tr.enabled else {}
+        with tr.span("spmd.lower", **attrs):
             jitted = self.program(pipeline, with_carry=with_carry)
             with record_hops(hops):
                 lowered = jitted.lower(*args)
         with tr.span("spmd.compile"):
             fn = lowered.compile()
+            # the op->layer map of this executable, read once per compile
+            # (also when it came from the persistent compile cache)
+            text = fn.as_text()
+            module, layers = module_name(text), op_layers(text)
         collectives = None
         if tr.enabled:
             with tr.span("spmd.introspect"):
@@ -542,7 +551,7 @@ class SPMDExecutor:
         entry = _CacheEntry(
             pipeline=pipeline, fn=fn,
             has_sort=any(isinstance(s, SortStage) for s in pipeline.stages),
-            hops=hops, collectives=collectives)
+            hops=hops, collectives=collectives, module=module, layers=layers)
         self._cache[key] = entry
         if len(self._cache) > self.cache_size:
             self._cache.popitem(last=False)
@@ -575,50 +584,6 @@ class SPMDExecutor:
                  hops=[{k: h[k] for k in ("axis", "num_dest", "chunks",
                                           "wire_bytes_per_device")}
                        for h in entry.hops])
-
-    # -- per-stage traced execution -------------------------------------------
-    def _stage_flow(self, pipeline: Dataflow, i: int) -> Dataflow:
-        key = (id(pipeline), "stage", i)
-        hit = self._subflows.get(key)
-        if hit is not None and hit[0] is pipeline:
-            return hit[1]
-        sub = Dataflow(stages=(pipeline.stages[i],), codec=pipeline.codec)
-        self._subflows[key] = (pipeline, sub)
-        return sub
-
-    def _run_staged(self, df: Dataflow, records: Any, valid: Any,
-                    tr) -> DataflowResult:
-        """One compiled program per stage, so every stage — and every
-        shuffle/sort hop — is its own span with wire-byte and chunk
-        attributes. Delivers the same records as the fused program (each
-        stage is a one-stage sub-pipeline over the identical shard
-        layout)."""
-        records = jax.tree.map(jnp.asarray, records)
-        if valid is None:
-            valid = jnp.ones((_leading(records),), jnp.bool_)
-        total_dropped = 0
-        with tr.span("spmd.run.staged", pipeline=df.describe(),
-                     stages=len(df.stages)) as root:
-            for i, stage in enumerate(df.stages):
-                kind = _STAGE_KIND[type(stage)]
-                name = (f"hop[{i}]:{kind}" if kind in ("shuffle", "sort")
-                        else f"stage[{i}]:{kind}")
-                with tr.span(name) as sp:
-                    res = self.run(self._stage_flow(df, i), records,
-                                   valid=valid, trace=tr)
-                    records, valid = res.records, res.valid
-                    d = int(res.dropped)
-                    total_dropped += d
-                    attrs: Dict[str, Any] = {"dropped": d}
-                    entry = self._last_entry
-                    if entry is not None and entry.hops:
-                        attrs["wire_bytes_per_device"] = sum(
-                            h["wire_bytes_per_device"] for h in entry.hops)
-                        attrs["chunks"] = entry.hops[0]["chunks"]
-                    sp.set(**attrs)
-            root.set(dropped=total_dropped)
-        return DataflowResult(records=records, valid=valid,
-                              dropped=total_dropped, trace=tr)
 
     # -- segmented execution + device-loss recovery ---------------------------
     def _sub_executor(self, mesh: Mesh) -> "SPMDExecutor":
@@ -746,33 +711,37 @@ class SPMDExecutor:
             new_carry = (None, None)
             for i, stage in enumerate(df.stages):
                 if isinstance(stage, MapStage):
-                    records = stage.fn(records)
-                    if _leading(records) != valid.shape[0]:
-                        valid = jnp.ones((_leading(records),), jnp.bool_)
+                    with jax.named_scope(UDF):
+                        records = stage.fn(records)
+                        if _leading(records) != valid.shape[0]:
+                            valid = jnp.ones((_leading(records),), jnp.bool_)
                 elif isinstance(stage, ReduceStage):
-                    if i == carry_at:
-                        # merge last batch's aggregate into this group; the
-                        # reduce output below becomes the next batch's carry
-                        records = jax.tree.map(
-                            lambda a, c: jnp.concatenate([a, c], axis=0),
-                            records, carry_records)
-                        valid = jnp.concatenate(
-                            [valid, carry_valid.reshape(-1)])
-                    records, valid, rd = _split_reduce_out(
-                        stage.fn(records, valid))
-                    valid = valid.reshape(-1)
-                    if rd is not None:
-                        dropped += jax.lax.psum(
-                            jnp.asarray(rd, jnp.int32), axes)
-                    if i == carry_at:
-                        cap = carry_valid.reshape(-1).shape[0]
-                        c_rec, c_valid, c_drop = _compact_carry(
-                            records, valid, cap)
-                        new_carry = (c_rec, c_valid)
-                        dropped += jax.lax.psum(
-                            c_drop.astype(jnp.int32), axes)
+                    with jax.named_scope(UDF):
+                        if i == carry_at:
+                            # merge last batch's aggregate into this group;
+                            # the reduce output below becomes the next
+                            # batch's carry
+                            records = jax.tree.map(
+                                lambda a, c: jnp.concatenate([a, c], axis=0),
+                                records, carry_records)
+                            valid = jnp.concatenate(
+                                [valid, carry_valid.reshape(-1)])
+                        records, valid, rd = _split_reduce_out(
+                            stage.fn(records, valid))
+                        valid = valid.reshape(-1)
+                        if rd is not None:
+                            dropped += jax.lax.psum(
+                                jnp.asarray(rd, jnp.int32), axes)
+                        if i == carry_at:
+                            cap = carry_valid.reshape(-1).shape[0]
+                            c_rec, c_valid, c_drop = _compact_carry(
+                                records, valid, cap)
+                            new_carry = (c_rec, c_valid)
+                            dropped += jax.lax.psum(
+                                c_drop.astype(jnp.int32), axes)
                 elif isinstance(stage, ShuffleStage):
-                    ids = jnp.asarray(stage.by(records)).reshape(-1)
+                    with jax.named_scope(UDF):
+                        ids = jnp.asarray(stage.by(records)).reshape(-1)
                     records, valid, d, _ = self._exchange(
                         records, valid, ids, stage.num_buckets,
                         stage.capacity_factor, stage.chunks)
@@ -786,24 +755,31 @@ class SPMDExecutor:
                     raise TypeError(f"unknown stage {stage!r}")
             return records, valid, dropped, sentinel, new_carry
 
+        # the program is named ``sphere_spmd`` (HLO module
+        # ``jit_sphere_spmd``): the name labels it in a profile and in the
+        # op->layer table, and the persistent compile cache keys it by this
+        # name and by its IR without locations — layer scopes, being
+        # locations, never change a key, so an executable compiled without
+        # them is never loaded under this name
         if with_carry:
-            def local(records, valid, carry_records, carry_valid):
+            def sphere_spmd(records, valid, carry_records, carry_valid):
                 records, valid, dropped, sentinel, (c_rec, c_valid) = body(
                     records, valid, carry_records, carry_valid)
                 return records, valid, dropped, sentinel, c_rec, c_valid
 
-            mapped = shard_map(local, mesh=self.mesh,
+            mapped = shard_map(sphere_spmd, mesh=self.mesh,
                                in_specs=(spec, spec, spec, spec),
                                out_specs=(spec, spec, P(), P(), spec, spec),
                                check_vma=False)
             return jax.jit(mapped)
 
-        def local(records, valid):
+        def sphere_spmd(records, valid):
             records, valid, dropped, sentinel, _ = body(records, valid,
                                                         None, None)
             return records, valid, dropped, sentinel
 
-        mapped = shard_map(local, mesh=self.mesh, in_specs=(spec, spec),
+        mapped = shard_map(sphere_spmd, mesh=self.mesh,
+                           in_specs=(spec, spec),
                            out_specs=(spec, spec, P(), P()), check_vma=False)
         return jax.jit(mapped)
 
@@ -845,13 +821,16 @@ class SPMDExecutor:
         consumer here regroups from the decoded records, so bucket/src
         metadata would be dead bytes."""
         codec = RecordCodec.from_example(records)
-        packed = codec.pack(records)
+        with jax.named_scope(MOVE):
+            packed = codec.pack(records)
         plan = self._stage_plan(num_buckets, packed.shape[0], capacity_factor,
                                 chunks)
-        res = plan.shuffle(packed, ids.astype(jnp.int32), valid=valid,
-                           wire_meta="min")
-        flat = res.data.reshape(-1, codec.nbytes)
-        return codec.unpack(flat), res.valid.reshape(-1), res.dropped, plan
+        with jax.named_scope(EXCHANGE):
+            res = plan.shuffle(packed, ids.astype(jnp.int32), valid=valid,
+                               wire_meta="min")
+        with jax.named_scope(MOVE):
+            out = codec.unpack(res.data.reshape(-1, codec.nbytes))
+        return out, res.valid.reshape(-1), res.dropped, plan
 
     def _sort(self, records, valid, stage: SortStage):
         """Range-partition shuffle (stage 1) + local **segmented** sort
@@ -873,6 +852,11 @@ class SPMDExecutor:
         (the same §3.5.1 bounded-skew contract as the shuffle itself —
         impossible when ``buckets_per_device == 1``).
 
+        Layer scopes (:mod:`repro.obs.layers`): ``sphere.bucket`` holds the
+        splitters and both ``searchsorted`` calls, ``sphere.segsort`` the
+        segment sort and the sentinel keys feeding it, ``sphere.move`` the
+        final gather; the exchange and partition/pack bring their own.
+
         Returns ``(records, valid, dropped, sentinel_hits)`` —
         ``sentinel_hits`` counts real received keys equal to the padding
         sentinel (the key dtype's maximum, via
@@ -885,54 +869,67 @@ class SPMDExecutor:
         """
         nb = (self.plan.num_buckets if self.plan is not None
               else stage.num_buckets or self.axis_size)
-        if stage.splitters is not None:
-            spl = jnp.asarray(stage.splitters)
-            if spl.shape[0] != nb - 1:
-                raise ValueError(f"{spl.shape[0]} splitters for {nb} buckets")
-        else:
-            spl = jnp.linspace(0, _KEY_MAX, nb + 1)[1:-1].astype(jnp.int32)
-        keys = jnp.asarray(stage.key(records)).astype(jnp.int32).reshape(-1)
-        bucket = jnp.searchsorted(spl, keys, side="right").astype(jnp.int32)
+        with jax.named_scope(BUCKET):
+            if stage.splitters is not None:
+                spl = jnp.asarray(stage.splitters)
+                if spl.shape[0] != nb - 1:
+                    raise ValueError(
+                        f"{spl.shape[0]} splitters for {nb} buckets")
+            else:
+                spl = jnp.linspace(0, _KEY_MAX, nb + 1)[1:-1].astype(
+                    jnp.int32)
+            keys = jnp.asarray(stage.key(records)).astype(
+                jnp.int32).reshape(-1)
+            bucket = jnp.searchsorted(spl, keys, side="right").astype(
+                jnp.int32)
         records, valid, dropped, plan = self._exchange(
             records, valid, bucket, nb, stage.capacity_factor, stage.chunks)
 
         # stage 2: bucket-major regroup (O(n) partition, stable) ...
-        keys = jnp.asarray(stage.key(records)).astype(jnp.int32).reshape(-1)
-        sentinel = kops.pad_sentinel(keys.dtype)
-        skey = jnp.where(valid, keys, sentinel)
-        r = skey.shape[0]
         bpd = plan.buckets_per_device
-        seg_cap = _segment_capacity(r, bpd, stage.capacity_factor)
-        # resolve the stage-2 kernel now (trace-time): stability decides
-        # whether sentinel-collision accounting is needed at all.
-        algo = kops.resolve_sort_algo(bpd, seg_cap, skey.dtype,
-                                      self.sort_algo, kv=True)
-        if autotune.is_stable(algo):
-            sentinel_hits = jnp.zeros((), jnp.int32)
-        else:
-            sentinel_hits = jax.lax.psum(
-                jnp.sum((valid & (keys == sentinel)).astype(jnp.int32)),
-                plan.pmean_axes())
-        local = (jnp.searchsorted(spl, skey, side="right").astype(jnp.int32)
-                 - plan.device_index() * bpd)
-        seg_dest = jnp.where(valid, local, bpd)       # invalid -> overflow
+        with jax.named_scope(SEGSORT):
+            keys = jnp.asarray(stage.key(records)).astype(
+                jnp.int32).reshape(-1)
+            sentinel = kops.pad_sentinel(keys.dtype)
+            skey = jnp.where(valid, keys, sentinel)
+            seg_cap = _segment_capacity(skey.shape[0], bpd,
+                                        stage.capacity_factor)
+            # resolve the stage-2 kernel now (trace-time): stability decides
+            # whether sentinel-collision accounting is needed at all.
+            algo = kops.resolve_sort_algo(bpd, seg_cap, skey.dtype,
+                                          self.sort_algo, kv=True)
+            if autotune.is_stable(algo):
+                sentinel_hits = jnp.zeros((), jnp.int32)
+            else:
+                sentinel_hits = jax.lax.psum(
+                    jnp.sum((valid & (keys == sentinel)).astype(jnp.int32)),
+                    plan.pmean_axes())
+        with jax.named_scope(BUCKET):
+            local = (jnp.searchsorted(spl, skey, side="right").astype(
+                jnp.int32) - plan.device_index() * bpd)
+            seg_dest = jnp.where(valid, local, bpd)   # invalid -> overflow
         leaves, treedef = jax.tree.flatten(records)
         tiles, in_rng, _, seg_drop = kops.partition_pack(
             [skey] + leaves, seg_dest, bpd, seg_cap,
             use_pallas=self.use_pallas)
-        dropped += jax.lax.psum(seg_drop, plan.pmean_axes())
+        with jax.named_scope(MOVE):
+            dropped += jax.lax.psum(seg_drop, plan.pmean_axes())
 
         # ... then one multi-segment sort: bpd rows of seg_cap. Empty slots
         # carry the max-key sentinel so each segment's valid records end up
         # in its prefix — exactly where ``in_rng`` already points (pads sit
         # in the suffix, which stable kernels preserve even on key ties).
-        seg_keys = jnp.where(in_rng, tiles[0], sentinel)
-        pos = jnp.arange(bpd * seg_cap, dtype=jnp.int32).reshape(bpd, seg_cap)
-        _, order = kops.sort_kv_segments(seg_keys, pos, algo=algo)
-        order = order.reshape(-1)
-        records = jax.tree.unflatten(treedef, [
-            jnp.take(t.reshape((bpd * seg_cap,) + t.shape[2:]), order, axis=0)
-            for t in tiles[1:]])
+        with jax.named_scope(SEGSORT):
+            seg_keys = jnp.where(in_rng, tiles[0], sentinel)
+            pos = jnp.arange(bpd * seg_cap, dtype=jnp.int32).reshape(
+                bpd, seg_cap)
+            _, order = kops.sort_kv_segments(seg_keys, pos, algo=algo)
+            order = order.reshape(-1)
+        with jax.named_scope(MOVE):
+            records = jax.tree.unflatten(treedef, [
+                jnp.take(t.reshape((bpd * seg_cap,) + t.shape[2:]), order,
+                         axis=0)
+                for t in tiles[1:]])
         return records, in_rng.reshape(-1), dropped, sentinel_hits
 
 
@@ -1050,8 +1047,9 @@ class HostExecutor:
         phase_times: List[Dict[str, Any]] = []
 
         phases = _phases(pipeline)
-        with tr.span("host.run", pipeline=pipeline.describe(),
-                     files=len(paths)) as root:
+        attrs = ({"pipeline": pipeline.describe(), "files": len(paths)}
+                 if tr.enabled else {})
+        with tr.span("host.run", **attrs) as root:
             for pi, phase in enumerate(phases):
                 t0 = time.monotonic()
                 term = phase.terminator

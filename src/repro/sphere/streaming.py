@@ -525,10 +525,12 @@ class StreamExecutor:
         batch, valid, n = self._assemble(tickets)
         if self.carry_capacity and self._carry is None:
             self._carry = self._init_carry(batch, valid)
-        with tr.span(f"stream.batch[{self._steps}]", records=n,
-                     tenants=sorted({t.tenant for t in tickets}),
-                     admission_wait_max=max(now - t.admitted_at
-                                            for t in tickets)) as bsp:
+        attrs = ({"records": n,
+                  "tenants": sorted({t.tenant for t in tickets}),
+                  "admission_wait_max": max(now - t.admitted_at
+                                            for t in tickets)}
+                 if tr.enabled else {})
+        with tr.span(f"stream.batch[{self._steps}]", **attrs) as bsp:
             t0 = time.monotonic()
             with self.inner.mesh:
                 res = self.inner.run(self.pipeline, batch, valid=valid,

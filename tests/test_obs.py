@@ -21,6 +21,7 @@ from repro.core.mapreduce import default_hash, reduce_by_key_sum
 from repro.core.records import RecordCodec
 from repro.launch.train import make_sector
 from repro.obs import NULL_TRACER, REGISTRY, MetricsRegistry, Tracer
+from repro.obs import layers
 from repro.sphere.dataflow import Dataflow, HostExecutor, SPMDExecutor
 from repro.sphere.spe import SPE
 from repro.sphere.streaming import StreamExecutor, TenantQueue
@@ -273,33 +274,124 @@ def test_untraced_run_records_no_spans_but_counts_runs():
     assert "spmd.dropped" not in snap
 
 
-def test_staged_trace_matches_fused_result():
+# -- spans on the profiler's clock -------------------------------------------
+
+
+def _host_events(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` session (host spans, no Python
+    calls); the host plane's events as ``(name, start_ns, end_ns)``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU" for line in p.lines
+            for e in line.events]
+
+
+def test_tracer_spans_reach_the_profiler(tmp_path):
+    tr = Tracer(clock=_fake_clock())
+
+    def work():
+        with tr.span("outer", kind="test"):
+            with tr.span("inner"):
+                pass
+    events = _host_events(tmp_path, work)
+    got = {n: (s, e) for n, s, e in events if n in ("outer", "inner")}
+    assert got["outer"][0] <= got["inner"][0]
+    assert got["inner"][1] <= got["outer"][1]
+    # the tracer's own record keeps its clock and attributes
+    assert [s.name for s in tr.buffer.spans()] == ["inner", "outer"]
+    assert tr.buffer.spans()[-1].attrs == {"kind": "test"}
+
+
+def test_untraced_run_spans_enclose_the_program_ops(tmp_path):
+    """With NULL_TRACER, a run still leaves ``spmd.run``, ``spmd.execute``
+    and ``spmd.sentinel_check`` on the host plane of a profile, on the
+    clock of the program's op events, which the op->layer map names."""
+    mesh = jax.make_mesh((1,), ("data",))
+    ex = SPMDExecutor(mesh)
+    df = Dataflow.source().sort(key=lambda r: r["key"], num_buckets=4)
+    rec = {"key": jnp.asarray(np.arange(256, dtype=np.int32)[::-1])}
+    valid = jnp.ones((256,), jnp.bool_)
+    ex.run(df, rec, valid=valid)                 # compiles outside
+    jax.block_until_ready((rec, valid))
+
+    def run():
+        res = ex.run(df, rec, valid=valid)
+        jax.block_until_ready(res.records)
+    events = _host_events(tmp_path, run)
+    module, op_map = layers.last_run()
+    assert module == "jit_sphere_spmd" and op_map
+    spans = {n: (s, e) for n, s, e in events if n.startswith("spmd.")}
+    assert set(spans) == {"spmd.run", "spmd.execute", "spmd.sentinel_check"}
+    ops = [(s, e) for n, s, e in events if n in op_map]
+    assert ops
+    run_s, run_e = spans["spmd.run"]
+    assert all(run_s <= s and e <= run_e for s, e in ops)
+    assert spans["spmd.execute"][0] <= min(s for s, _ in ops)
+    check_s, check_e = spans["spmd.sentinel_check"]
+    assert run_s <= check_s and check_e <= run_e
+
+
+def test_published_map_is_the_program_that_ran():
     mesh = jax.make_mesh((1,), ("data",))
     ex = SPMDExecutor(mesh)
     df = _wordcount()
-    fused = ex.run(df, _records())
-    tr = Tracer()
-    staged = ex.run(df, _records(), trace=tr, trace_stages=True)
-    assert _counts(staged) == _counts(fused)
-    names = [s.name for s in tr.buffer.spans()]
-    assert "spmd.run.staged" in names
-    stage_names = [n for n in names
-                   if n.startswith(("stage[", "hop["))]
-    assert stage_names == ["stage[0]:map", "hop[1]:shuffle",
-                           "stage[2]:reduce"]
-    hop = next(s for s in tr.buffer.spans() if s.name == "hop[1]:shuffle")
-    assert hop.attrs["wire_bytes_per_device"] > 0
+    ex.run(df, _records())
+    module, op_map = layers.last_run()
+    assert module == "jit_sphere_spmd"
+    assert op_map is ex._cache[next(iter(ex._cache))].layers
+    # the wordcount's map and reduce run in the UDF scope, its shuffle hop
+    # in the exchange scope
+    assert {"sphere.udf", "sphere.exchange", "sphere.move",
+            "sphere.rank"} <= set(op_map.values())
+    assert set(op_map.values()) <= set(layers.SCOPES.values())
 
 
-def test_trace_stages_rejects_carry():
-    mesh = jax.make_mesh((1,), ("data",))
-    ex = SPMDExecutor(mesh)
-    with pytest.raises(ValueError, match="carry"):
-        ex.run(_wordcount(stream=True), _records(), trace=Tracer(),
-               trace_stages=True,
-               carry=({"key": jnp.zeros((4,), jnp.int32),
-                       "value": jnp.zeros((4,), jnp.int32)},
-                      jnp.zeros((4,), jnp.bool_)))
+_CACHED_MAP = """
+import json, jax, jax.numpy as jnp, numpy as np
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro.obs import layers
+from repro.sphere.dataflow import Dataflow, SPMDExecutor
+ex = SPMDExecutor(jax.make_mesh((1,), ("data",)))
+df = Dataflow.source().sort(key=lambda r: r["key"], num_buckets=4)
+ex.run(df, {"key": jnp.arange(64, dtype=jnp.int32)[::-1]})
+print(json.dumps(layers.last_run()))
+"""
+
+
+def test_op_layer_map_of_a_program_from_the_compile_cache(tmp_path):
+    """The second process loads the executable from the persistent compile
+    cache and reads the same op->layer map from it."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..",
+                                      "src")
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    outs, entries = [], []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", _CACHED_MAP], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-3000:]
+        outs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+        entries.append(sorted(os.listdir(tmp_path / "cache")))
+    assert entries[0] and entries[1] == entries[0]     # nothing recompiled
+    assert outs[0][0] == "jit_sphere_spmd"
+    assert outs[1] == outs[0] and "sphere.bucket" in outs[0][1].values()
 
 
 # -- host executor instrumentation -------------------------------------------

@@ -13,6 +13,10 @@ process may load the TPU library, and every pytest worker imports this
 file.
 """
 
+import base64
+import contextlib
+import hashlib
+import re
 import sys
 from pathlib import Path
 
@@ -109,3 +113,103 @@ def test_terasort_program_compiles_on_four_chips(layout, topo, monkeypatch):
     want = 2 if layout == "hierarchical" else 1
     assert ts.all_to_all() == want
     assert "all-to-all" in text
+
+
+_METADATA = re.compile(r',? metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+_KERNEL_BODY = re.compile(r'"body":"([^"]+)"')
+_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def _kernel_ir(body_b64: str) -> str:
+    """A Mosaic kernel's serialized IR with its debug locations stripped:
+    the body embeds the source paths, lines and name stack it was traced
+    with."""
+    from jax._src.lib.mlir import ir, passmanager
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body_b64))
+        passmanager.PassManager.parse(
+            "builtin.module(strip-debuginfo)").run(module.operation)
+        return hashlib.sha256(str(module).encode()).hexdigest()
+
+
+def hlo_without_debug_info(text: str) -> str:
+    """Optimised HLO text less what only debugging reads: every
+    ``metadata={...}``, the stack-frame tables, and the debug locations
+    inside each Mosaic kernel's body."""
+    out, skipping = [], False
+    for line in text.splitlines():
+        if line in _TABLES:
+            skipping = True
+        elif skipping and not line[:1].isdigit() and line.strip():
+            skipping = False
+        if skipping or not line.strip():
+            continue
+        line = _METADATA.sub("", line)
+        out.append(_KERNEL_BODY.sub(
+            lambda m: '"body":"' + _kernel_ir(m.group(1)) + '"', line))
+    return "\n".join(out)
+
+
+def test_layer_scopes_change_only_metadata(topo, monkeypatch):
+    """The one-chip terasort program compiled for the chip with its layer
+    scopes and with ``jax.named_scope`` made a no-op: the same program,
+    instruction for instruction, once debug info is set aside."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+
+    def compiled_text():
+        ts = chip_smoke.Terasort(mesh, ("data",), FOUR_CHIP_TEST_N,
+                                 sort_algo="bitonic")
+        return ts.compile()[0].as_text()
+
+    scoped = compiled_text()
+    assert "sphere.bucket" in scoped and "sphere.segsort" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled_text()
+    assert "sphere." not in plain
+    assert hlo_without_debug_info(scoped) == hlo_without_debug_info(plain)
+
+
+def test_loops_the_compiler_writes_take_a_layer(topo, monkeypatch):
+    """At 2^20 records a chip the compiler relays out the exchange's byte
+    rows in ``while`` loops of its own (none at the smaller test size),
+    two before the all-to-all and two after it: they, and every
+    instruction of their bodies, are the exchange's. Every other loop, and
+    every kernel, sort, gather, scatter and all-to-all of the four-chip
+    program, maps to a layer too."""
+    from repro.core.introspect import op_layers
+    from repro.obs.layers import EXCHANGE, SCOPES
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    ts = chip_smoke.Terasort(Mesh(np.array(topo.devices), ("data",)),
+                             ("data",), 1 << 20, sort_algo="bitonic")
+    text = ts.compile()[0].as_text()
+    got = op_layers(text)
+    body_of = dict(re.findall(r"%([\w.\-]+) = [^\n]*? while\([^\n]*?"
+                              r"body=%([\w.\-]+)", text))
+    wire = [w for w, _ in re.findall(
+        r"%([\w.\-]+) = \([^\n]*u8\[[^\n]*\) while\((?![^\n]*op_name)"
+        r"([^\n]*)", text)]
+    assert len(wire) == 4 and len(body_of) == 6   # + two searchsorted
+    wire_bodies = {body_of[w] for w in wire}
+    computation, missing = None, []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+) .*\{$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        inst = re.match(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*? "
+                        r"([a-z][\w\-]*)\(", line)
+        if inst is None:
+            continue
+        name, opcode = inst.groups()
+        if name in wire or computation in wire_bodies:
+            assert got.get(name) == EXCHANGE, line[:120]
+        elif (computation in body_of.values() or opcode in (
+                "while", "all-to-all", "gather", "scatter", "sort")
+              or "tpu_custom_call" in line) \
+                and got.get(name) not in SCOPES.values():
+            missing.append(line[:120])
+    assert missing == []
