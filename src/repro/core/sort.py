@@ -10,8 +10,9 @@ executed by :class:`repro.sphere.dataflow.SPMDExecutor` (or, over
 Sector-stored records, by the host executor with bucket files).
 
 Stage 1 ("hashing"): every record's key is range-partitioned into a bucket
-(``searchsorted`` against splitters — the paper's T_0 < T_1 < ... thresholds)
-and shuffled to the device owning that bucket via
+(``searchsorted`` against splitters — the paper's T_0 < T_1 < ... thresholds;
+with the default even splitters, :func:`uniform_bucket`'s division) and
+shuffled to the device owning that bucket via
 :class:`repro.core.shuffle.ShufflePlan` (flat or two-level wide-area).
 
 Stage 2 ("sort each bucket"): each device sorts its received records — the
@@ -37,6 +38,7 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
@@ -56,11 +58,32 @@ class SortResult:
     dropped: jax.Array
 
 
-def uniform_splitters(num_buckets: int, key_min: int = 0,
-                      key_max: int = KEY_MAX) -> jnp.ndarray:
-    """Equal-width range splitters (terasort keys are uniform)."""
-    edges = jnp.linspace(key_min, key_max, num_buckets + 1)[1:-1]
-    return edges.astype(jnp.int32)
+def bucket_width(num_buckets: int) -> int:
+    """Width of the even key ranges the default splitters cut the
+    non-negative int32 keys ``[0, 2^31)`` into. Rounded down, so every
+    splitter fits an int32 and no bucket is empty; the last bucket takes
+    the remainder, fewer than ``num_buckets`` keys more than the others."""
+    return 2**31 // num_buckets
+
+
+def uniform_splitters(num_buckets: int) -> np.ndarray:
+    """The default splitters: ``bucket_width * (1 .. num_buckets - 1)``,
+    exact int32 (terasort keys are uniform). Every executor's default; the
+    one definition both the arithmetic and the search path agree on."""
+    w = bucket_width(num_buckets)
+    return (w * np.arange(1, num_buckets, dtype=np.int64)).astype(np.int32)
+
+
+def uniform_bucket(keys: jax.Array, num_buckets: int) -> jax.Array:
+    """``searchsorted(uniform_splitters(num_buckets), keys, side="right")``
+    for every int32 key, by one division instead of a search: negative keys
+    go to bucket 0, keys past the last splitter (the int32 maximum among
+    them) to ``num_buckets - 1``."""
+    if num_buckets == 1:
+        return jnp.zeros(keys.shape, jnp.int32)
+    w = jnp.int32(bucket_width(num_buckets))
+    b = jax.lax.div(jnp.maximum(keys.astype(jnp.int32), 0), w)
+    return jnp.minimum(b, num_buckets - 1)
 
 
 def sampled_splitters(keys: jax.Array, num_buckets: int,
@@ -135,9 +158,7 @@ def terasort(
         axes = (axis,) if isinstance(axis, str) else tuple(axis)
         axis_size = math.prod(mesh.shape[a] for a in axes)
         num_buckets = axis_size * buckets_per_device
-    if splitters is None:
-        splitters = uniform_splitters(num_buckets)
-    elif splitters.shape[0] != num_buckets - 1:
+    if splitters is not None and splitters.shape[0] != num_buckets - 1:
         raise ValueError(f"{splitters.shape[0]} splitters for "
                          f"{num_buckets} buckets")
 

@@ -8,8 +8,8 @@ instruction's ``metadata={op_name="jit(...)/.../sphere.<layer>/..."}``,
 and the innermost scope of :data:`SCOPES` on that path names the op's
 layer (:func:`repro.core.introspect.layer_of`).
 
-- ``sphere.bucket``: range bucketing, the splitters and both
-  ``searchsorted`` calls of a sort;
+- ``sphere.bucket``: range bucketing, the splitters and both bucketings
+  of a sort (a division with the default splitters, else ``searchsorted``);
 - ``sphere.rank``: ``kernels.ops.partition_rank`` (Pallas kernel or jnp
   oracle);
 - ``sphere.move``: row moves, partition/pack's slot-map scatter and
@@ -30,11 +30,13 @@ Every run of the executor notes the executable's HLO module name and its
 map ``{instruction name: layer}``, read once when it was compiled, so a
 reader of a ``jax.profiler`` trace (whose op events are named by their
 instructions) can sum device time per layer without reading HLO by hand.
+With it goes the range-bucketing path of each sort stage of that program
+(``"arithmetic"`` or ``"search"``, :func:`last_bucketing`).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 BUCKET = "sphere.bucket"
 RANK = "sphere.rank"
@@ -51,15 +53,26 @@ SCOPES: Dict[str, str] = {
 }
 
 _last: Optional[Tuple[str, Dict[str, str]]] = None
+_last_bucketing: Tuple[str, ...] = ()
 
 
-def ran(module: str, op_layers: Dict[str, str]) -> None:
-    """Note that the executable of ``module`` with this map ran last."""
-    global _last
+def ran(module: str, op_layers: Dict[str, str],
+        bucketing: Sequence[str] = ()) -> None:
+    """Note that the executable of ``module`` with this map, whose sort
+    stages bucket by ``bucketing``, ran last."""
+    global _last, _last_bucketing
     _last = (module, op_layers)
+    _last_bucketing = tuple(bucketing)
 
 
 def last_run() -> Optional[Tuple[str, Dict[str, str]]]:
     """``(module name, op->layer map)`` of the program that ran last, or
     None before any run."""
     return _last
+
+
+def last_bucketing() -> Tuple[str, ...]:
+    """The range-bucketing path of each sort stage, in stage order, of the
+    program that ran last: ``"arithmetic"`` (the default even splitters,
+    one division) or ``"search"`` (given splitters, ``searchsorted``)."""
+    return _last_bucketing

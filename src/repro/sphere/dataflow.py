@@ -71,6 +71,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 from repro.core.introspect import collective_counts, module_name, op_layers
 from repro.core.records import RecordCodec
+from repro.core.sort import uniform_bucket, uniform_splitters
 from repro.core.shuffle import ShufflePlan, record_hops
 from repro.kernels import autotune
 from repro.kernels import ops as kops
@@ -78,8 +79,6 @@ from repro.obs import layers as obs_layers
 from repro.obs.layers import BUCKET, MOVE, EXCHANGE, SEGSORT, UDF
 from repro.obs.metrics import REGISTRY
 from repro.obs.trace import NULL_TRACER
-
-_KEY_MAX = np.iinfo(np.int32).max
 
 
 # -- pipeline description ----------------------------------------------------
@@ -312,7 +311,9 @@ class _CacheEntry(NamedTuple):
     collective counts (only computed when the entry was built under an
     active tracer — the extra trace is not free). ``module`` is the
     executable's HLO module name and ``layers`` its op->layer map
-    (:func:`repro.core.introspect.op_layers`), read once per compile."""
+    (:func:`repro.core.introspect.op_layers`), read once per compile.
+    ``bucketing`` names the range-bucketing path of each sort stage, in
+    stage order (:func:`bucketing`)."""
 
     pipeline: "Dataflow"
     fn: Callable
@@ -321,6 +322,14 @@ class _CacheEntry(NamedTuple):
     collectives: Optional[Dict[str, int]]
     module: str
     layers: Dict[str, str]
+    bucketing: Tuple[str, ...]
+
+
+def bucketing(stage: SortStage) -> str:
+    """How a sort stage buckets its keys: ``"arithmetic"`` (one division,
+    :func:`repro.core.sort.uniform_bucket`) with the default even splitters,
+    ``"search"`` (``searchsorted``) against splitters the stage was given."""
+    return "arithmetic" if stage.splitters is None else "search"
 
 
 _STAGE_KIND = {MapStage: "map", ShuffleStage: "shuffle",
@@ -494,7 +503,7 @@ class SPMDExecutor:
                 REGISTRY.counter("spmd.cache.hits").inc()
                 self._cache.move_to_end(key)
                 root.set(cache="hit")
-            obs_layers.ran(entry.module, entry.layers)
+            obs_layers.ran(entry.module, entry.layers, entry.bucketing)
             with tr.span("spmd.execute", hops=len(entry.hops)):
                 out = entry.fn(*args)
                 if tr.enabled:
@@ -548,10 +557,11 @@ class SPMDExecutor:
         if tr.enabled:
             with tr.span("spmd.introspect"):
                 collectives = collective_counts(jitted, *args)
+        sorts = [s for s in pipeline.stages if isinstance(s, SortStage)]
         entry = _CacheEntry(
-            pipeline=pipeline, fn=fn,
-            has_sort=any(isinstance(s, SortStage) for s in pipeline.stages),
-            hops=hops, collectives=collectives, module=module, layers=layers)
+            pipeline=pipeline, fn=fn, has_sort=bool(sorts), hops=hops,
+            collectives=collectives, module=module, layers=layers,
+            bucketing=tuple(bucketing(s) for s in sorts))
         self._cache[key] = entry
         if len(self._cache) > self.cache_size:
             self._cache.popitem(last=False)
@@ -852,10 +862,16 @@ class SPMDExecutor:
         (the same §3.5.1 bounded-skew contract as the shuffle itself —
         impossible when ``buckets_per_device == 1``).
 
+        Range bucketing (:func:`bucketing`): with the default even
+        splitters a key's bucket is one division
+        (:func:`repro.core.sort.uniform_bucket`); splitters the stage was
+        given are searched (``searchsorted``). Both give
+        ``searchsorted(splitters, key, side="right")``.
+
         Layer scopes (:mod:`repro.obs.layers`): ``sphere.bucket`` holds the
-        splitters and both ``searchsorted`` calls, ``sphere.segsort`` the
-        segment sort and the sentinel keys feeding it, ``sphere.move`` the
-        final gather; the exchange and partition/pack bring their own.
+        splitters and both bucketings (send and regroup), ``sphere.segsort``
+        the segment sort and the sentinel keys feeding it, ``sphere.move``
+        the final gather; the exchange and partition/pack bring their own.
 
         Returns ``(records, valid, dropped, sentinel_hits)`` —
         ``sentinel_hits`` counts real received keys equal to the padding
@@ -870,18 +886,21 @@ class SPMDExecutor:
         nb = (self.plan.num_buckets if self.plan is not None
               else stage.num_buckets or self.axis_size)
         with jax.named_scope(BUCKET):
-            if stage.splitters is not None:
+            if bucketing(stage) == "search":
                 spl = jnp.asarray(stage.splitters)
                 if spl.shape[0] != nb - 1:
                     raise ValueError(
                         f"{spl.shape[0]} splitters for {nb} buckets")
+
+                def bucket_of(k):
+                    return jnp.searchsorted(spl, k, side="right").astype(
+                        jnp.int32)
             else:
-                spl = jnp.linspace(0, _KEY_MAX, nb + 1)[1:-1].astype(
-                    jnp.int32)
+                def bucket_of(k):
+                    return uniform_bucket(k, nb)
             keys = jnp.asarray(stage.key(records)).astype(
                 jnp.int32).reshape(-1)
-            bucket = jnp.searchsorted(spl, keys, side="right").astype(
-                jnp.int32)
+            bucket = bucket_of(keys)
         records, valid, dropped, plan = self._exchange(
             records, valid, bucket, nb, stage.capacity_factor, stage.chunks)
 
@@ -905,8 +924,7 @@ class SPMDExecutor:
                     jnp.sum((valid & (keys == sentinel)).astype(jnp.int32)),
                     plan.pmean_axes())
         with jax.named_scope(BUCKET):
-            local = (jnp.searchsorted(spl, skey, side="right").astype(
-                jnp.int32) - plan.device_index() * bpd)
+            local = bucket_of(skey) - plan.device_index() * bpd
             seg_dest = jnp.where(valid, local, bpd)   # invalid -> overflow
         leaves, treedef = jax.tree.flatten(records)
         tiles, in_rng, _, seg_drop = kops.partition_pack(
@@ -1191,8 +1209,7 @@ class HostExecutor:
             if isinstance(term, SortStage):
                 keys = np.asarray(term.key(records)).astype(np.int32)
                 spl = (np.asarray(term.splitters) if term.splitters is not None
-                       else np.linspace(0, _KEY_MAX, nb + 1)[1:-1]
-                       .astype(np.int32))
+                       else uniform_splitters(nb))
                 ids = np.searchsorted(spl, keys, side="right")
             else:
                 ids = np.asarray(term.by(records)).reshape(-1)

@@ -177,9 +177,10 @@ def test_loops_the_compiler_writes_take_a_layer(topo, monkeypatch):
     """At 2^20 records a chip the compiler relays out the exchange's byte
     rows in ``while`` loops of its own (none at the smaller test size),
     two before the all-to-all and two after it: they, and every
-    instruction of their bodies, are the exchange's. Every other loop, and
-    every kernel, sort, gather, scatter and all-to-all of the four-chip
-    program, maps to a layer too."""
+    instruction of their bodies, are the exchange's. They are the only
+    loops: range bucketing with the default splitters is a division, not a
+    ``searchsorted`` loop. Every kernel, sort, gather, scatter and
+    all-to-all of the four-chip program maps to a layer too."""
     from repro.core.introspect import op_layers
     from repro.obs.layers import EXCHANGE, SCOPES
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
@@ -192,7 +193,7 @@ def test_loops_the_compiler_writes_take_a_layer(topo, monkeypatch):
     wire = [w for w, _ in re.findall(
         r"%([\w.\-]+) = \([^\n]*u8\[[^\n]*\) while\((?![^\n]*op_name)"
         r"([^\n]*)", text)]
-    assert len(wire) == 4 and len(body_of) == 6   # + two searchsorted
+    assert len(wire) == 4 and len(body_of) == 4
     wire_bodies = {body_of[w] for w in wire}
     computation, missing = None, []
     for line in text.splitlines():
