@@ -86,6 +86,47 @@ def uniform_bucket(keys: jax.Array, num_buckets: int) -> jax.Array:
     return jnp.minimum(b, num_buckets - 1)
 
 
+def is_byte_key(key) -> bool:
+    """Whether a sort key is a compound byte key: ``(n, k)`` uint8, ordered
+    by its ``k`` bytes lexicographically (memcmp order), as TeraSort's
+    10-byte keys are. Every other key is one word (cast to int32)."""
+    return key.ndim == 2 and key.dtype == np.uint8
+
+
+def key_words(key) -> Tuple:
+    """The ``ceil(k / 4)`` big-endian 32-bit words of ``(n, k)`` uint8 byte
+    keys, most significant first, the last zero-padded: ``(n,)`` uint32
+    each, numpy for a numpy key and jax otherwise. Comparing two keys' word
+    tuples as unsigned integers, word by word, compares the keys by memcmp.
+    Both executors order and bucket byte keys through it."""
+    xp = np if isinstance(key, np.ndarray) else jnp
+    k = key.shape[1]
+    words = []
+    for w in range(0, k, 4):
+        word = key[:, w].astype(xp.uint32) << 24
+        for j in range(1, min(4, k - w)):
+            word = word | (key[:, w + j].astype(xp.uint32) << (24 - 8 * j))
+        words.append(word)
+    return tuple(words)
+
+
+def word_bucket_key(first_word):
+    """The int32 a byte key is range-bucketed on: its leading 31 bits (the
+    first word of :func:`key_words` shifted right by one), in ``[0, 2^31)``
+    like a terasort int32 key, so the default splitters cut it evenly and
+    buckets stay monotone in the whole key."""
+    return (first_word >> 1).astype(np.int32)
+
+
+def key_layout(key) -> str:
+    """How a sort stage orders ``key``: ``"int32"`` (one word) or
+    ``"bytes<k>/<w>w"`` (``k`` key bytes in ``w`` words)."""
+    if not is_byte_key(key):
+        return "int32"
+    k = key.shape[1]
+    return f"bytes{k}/{-(-k // 4)}w"
+
+
 def sampled_splitters(keys: jax.Array, num_buckets: int,
                       sample_per_shard: int, mesh: Mesh,
                       axis: str = "data") -> jnp.ndarray:

@@ -12,13 +12,13 @@ Pallas kernels keep segments resident in VMEM; on the CPU container they run
 in interpret mode, where XLA's native sort often wins and the O(S²/chunk)
 matmul permutation makes radix a guaranteed loss. Rather than scatter
 ``use_pallas`` booleans through every call site, this module picks **per
-shape**: the first call for a given ``(kv, dtype, num_segments, segment_len,
-backend, mode)`` cell times every candidate on synthetic data and caches the
-winner for the life of the process — every later call replays the cached
-choice with zero measurement. The measured table can be exported (the kernel
-benchmark persists it into ``BENCH_kernels.json`` and CI uploads it as an
-artifact) and pre-loaded via ``REPRO_AUTOTUNE_TABLE=<path>`` so production
-runs never measure at all.
+shape**: the first call for a given ``(kv, dtype, key words, num_segments,
+segment_len, backend, mode)`` cell times every candidate on synthetic data
+and caches the winner for the life of the process — every later call
+replays the cached choice with zero measurement. The measured table can be
+exported (the kernel benchmark persists it into ``BENCH_kernels.json`` and
+CI uploads it as an artifact) and pre-loaded via
+``REPRO_AUTOTUNE_TABLE=<path>`` so production runs never measure at all.
 
 Resolution order (first hit wins):
 
@@ -127,17 +127,21 @@ def interpret_default() -> bool:
 
 
 def cell_key(num_segments: int, segment_len: int, dtype, kv: bool,
-             interpret: Optional[bool] = None) -> str:
-    """Stable string id of an autotune cell — also the JSON table key.
-    Memoized: this sits on the per-call sort dispatch path."""
+             interpret: Optional[bool] = None, words: int = 1) -> str:
+    """Stable string id of an autotune cell — also the JSON table key. A
+    compound key of ``words`` word planes keys its dtype as
+    ``<dtype>x<words>`` (``kv|int32x3|...``), so a one-word pick is never
+    replayed for it. Memoized: this sits on the per-call sort dispatch
+    path."""
     interp = interpret_default() if interpret is None else interpret
-    memo_k = (num_segments, segment_len, dtype, kv, interp,
-              jax.default_backend())
+    backend = jax.default_backend()
+    memo_k = (num_segments, segment_len, dtype, kv, interp, backend, words)
     key = _cell_key_memo.get(memo_k)
     if key is None:
         mode = "interp" if interp else "compiled"
-        key = (f"{'kv' if kv else 'keys'}|{jnp.dtype(dtype).name}"
-               f"|{num_segments}x{segment_len}|{memo_k[-1]}|{mode}")
+        name = jnp.dtype(dtype).name + (f"x{words}" if words > 1 else "")
+        key = (f"{'kv' if kv else 'keys'}|{name}"
+               f"|{num_segments}x{segment_len}|{backend}|{mode}")
         _cell_key_memo[memo_k] = key
     return key
 
@@ -195,7 +199,8 @@ def _load_table_env() -> None:
                else {})
 
 
-def _synth(num_segments: int, segment_len: int, dtype, kv: bool):
+def _synth(num_segments: int, segment_len: int, dtype, kv: bool,
+           words: int = 1):
     rng = np.random.default_rng(0)
     dtype = jnp.dtype(dtype)
     shape = (num_segments, segment_len)
@@ -209,6 +214,11 @@ def _synth(num_segments: int, segment_len: int, dtype, kv: bool):
         keys = jnp.asarray(
             rng.integers(0, (1 << 31) - 1, size=shape, dtype=np.int64)
             .astype(np.int32))
+    if words > 1:
+        # a compound key: uniform 32-bit words behind the first
+        keys = (keys,) + tuple(jnp.asarray(
+            rng.integers(-(1 << 31), 1 << 31, size=shape, dtype=np.int64)
+            .astype(np.int32)) for _ in range(words - 1))
     if not kv:
         return (keys,)
     vals = jnp.arange(num_segments * segment_len,
@@ -247,13 +257,14 @@ def _time(fn: Callable, args) -> float:
 
 
 def _envelope(algo: str, num_segments: int, segment_len: int, dtype,
-              interpret: bool) -> Optional[str]:
-    """Why ``algo`` cannot run this cell, or None. These are the only
-    reasons a candidate is skipped; any other failure propagates."""
+              interpret: bool, planes: int = 2) -> Optional[str]:
+    """Why ``algo`` cannot run this cell of ``planes`` operands (key words
+    and values), or None. These are the only reasons a candidate is
+    skipped; any other failure propagates."""
     from repro.kernels.bitonic_sort import bitonic_supported
     from repro.kernels.radix_sort import radix_supported
     if algo == "bitonic":
-        return bitonic_supported(segment_len)
+        return bitonic_supported(segment_len, planes=planes)
     if algo != "radix":
         return None
     reason = radix_supported(segment_len)
@@ -273,13 +284,14 @@ def _envelope(algo: str, num_segments: int, segment_len: int, dtype,
 
 
 def _measure(num_segments: int, segment_len: int, dtype, kv: bool,
-             interpret: bool) -> Choice:
-    args = _synth(num_segments, segment_len, dtype, kv)
+             interpret: bool, words: int = 1) -> Choice:
+    args = _synth(num_segments, segment_len, dtype, kv, words)
     n_elem = num_segments * segment_len
     melem: Dict[str, float] = {}
     skipped: Dict[str, str] = {}
     for algo in ALGOS:
-        reason = _envelope(algo, num_segments, segment_len, dtype, interpret)
+        reason = _envelope(algo, num_segments, segment_len, dtype, interpret,
+                           planes=words + 1)
         if reason is not None:
             skipped[algo] = reason
             continue
@@ -290,10 +302,11 @@ def _measure(num_segments: int, segment_len: int, dtype, kv: bool,
 
 
 def choose(num_segments: int, segment_len: int, dtype, *, kv: bool = True,
-           interpret: Optional[bool] = None) -> Choice:
+           interpret: Optional[bool] = None, words: int = 1) -> Choice:
     """Resolve the sort algorithm for one cell (see module docstring for
-    the resolution order). Safe to call during tracing: measurement runs
-    jitted candidates on synthetic concrete inputs."""
+    the resolution order); ``words`` counts a compound key's word planes
+    (every candidate orders by all of them). Safe to call during tracing:
+    measurement runs jitted candidates on synthetic concrete inputs."""
     forced = os.environ.get(FORCE_ENV)
     if forced:
         if forced not in ALGOS:
@@ -301,7 +314,7 @@ def choose(num_segments: int, segment_len: int, dtype, *, kv: bool = True,
                              f"{ALGOS}")
         return Choice(forced, "forced")
     interp = interpret_default() if interpret is None else interpret
-    key = cell_key(num_segments, segment_len, dtype, kv, interp)
+    key = cell_key(num_segments, segment_len, dtype, kv, interp, words)
     hit = _cached_view.get(key)
     if hit is not None:
         return hit
@@ -312,7 +325,8 @@ def choose(num_segments: int, segment_len: int, dtype, *, kv: bool = True,
           or os.environ.get(MEASURE_ENV) == "0"):
         choice = Choice("oracle", "static")
     else:
-        choice = _measure(num_segments, segment_len, dtype, kv, interp)
+        choice = _measure(num_segments, segment_len, dtype, kv, interp,
+                          words)
         if choice.source == "measured":
             MEASUREMENTS[key] += 1
     _cache[key] = choice

@@ -62,18 +62,21 @@ def _legacy_algo(use_pallas, algo: Optional[str], where: str) -> Optional[str]:
 
 
 def resolve_sort_algo(num_segments: int, segment_len: int, dtype,
-                      algo: Optional[str] = None, kv: bool = True) -> str:
+                      algo: Optional[str] = None, kv: bool = True,
+                      words: int = 1) -> str:
     """The algorithm :func:`sort_segments` / :func:`sort_kv_segments` will
     run for this cell: the forced/pinned/autotuned choice as a plain string,
     resolvable at trace time (callers use it to decide stability-dependent
-    guards before the sort runs). ``REPRO_KERNEL_FORCE`` beats a pinned
-    ``algo``."""
+    guards before the sort runs). ``words`` counts the key's word planes (a
+    compound key has more than one; the autotuner keys its cell by it).
+    ``REPRO_KERNEL_FORCE`` beats a pinned ``algo``."""
     if not os.environ.get(autotune.FORCE_ENV) and algo is not None:
         if algo not in autotune.ALGOS:
             raise ValueError(f"algo={algo!r}: expected one of "
                              f"{autotune.ALGOS} (or None to autotune)")
         return algo
-    return autotune.choose(num_segments, segment_len, dtype, kv=kv).algo
+    return autotune.choose(num_segments, segment_len, dtype, kv=kv,
+                           words=words).algo
 
 
 def bucket_histogram(bucket_ids: jnp.ndarray, num_buckets: int,
@@ -176,18 +179,24 @@ def sort_segments(keys: jnp.ndarray, use_pallas=_UNSET, *,
     return sort_segments_pallas(keys)
 
 
-def sort_kv_segments(keys: jnp.ndarray, values: jnp.ndarray,
+def sort_kv_segments(keys, values: jnp.ndarray,
                      use_pallas=_UNSET, *, algo: Optional[str] = None):
     """Sort each row of (keys, values) by key.
 
-    ``algo=None`` → autotuned per shape/backend; ``"radix"`` and
-    ``"oracle"`` are stable, ``"bitonic"`` is not (callers needing
-    stability check :func:`repro.kernels.autotune.is_stable` on the
+    ``keys`` is one ``(segments, segment)`` array, or a tuple of such word
+    arrays, most significant first, for a compound key ordered by its words
+    lexicographically (every algorithm compares all of them); the sorted
+    keys come back in the same form. ``algo=None`` → autotuned per
+    shape/backend and word count; ``"radix"`` and ``"oracle"`` are stable,
+    ``"bitonic"`` is not (callers needing stability check
+    :func:`repro.kernels.autotune.is_stable` on the
     :func:`resolve_sort_algo` result). ``use_pallas`` is deprecated.
     """
     algo = _legacy_algo(use_pallas, algo, "sort_kv_segments")
-    n, s = keys.shape
-    resolved = resolve_sort_algo(n, s, keys.dtype, algo, kv=True)
+    words = keys if isinstance(keys, tuple) else (keys,)
+    n, s = words[0].shape
+    resolved = resolve_sort_algo(n, s, words[0].dtype, algo, kv=True,
+                                 words=len(words))
     if resolved == "oracle":
         return ref.sort_kv_segments_ref(keys, values)
     if resolved == "radix":

@@ -253,11 +253,10 @@ def _from_i32(x: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.bitcast_convert_type(x, jnp.uint32)
 
 
-def sort_kv_segments_radix(keys: jnp.ndarray, values: jnp.ndarray,
+def sort_kv_segments_radix(keys, values: jnp.ndarray,
                            bits: Optional[int] = None,
                            rows_per_step: int = 8,
-                           interpret: Optional[bool] = None
-                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                           interpret: Optional[bool] = None):
     """Stable-sort each row of ``keys`` ascending, permuting ``values``
     alongside.
 
@@ -265,21 +264,31 @@ def sort_kv_segments_radix(keys: jnp.ndarray, values: jnp.ndarray,
     (NaN unsupported), values any 32-bit dtype (moved bit-exactly). Rows of
     equal keys keep their input order — the property the stage-2 segmented
     sort relies on to keep suffix padding behind real max-value keys.
-    ``interpret=None`` resolves through
-    :func:`repro.kernels.autotune.interpret_default`.
+    ``keys`` may be a tuple of word planes, most significant first (a
+    compound key): one LSD kernel call per word, least significant first,
+    each carrying every word plane and the values, orders the rows by the
+    words lexicographically (each call is stable). ``interpret=None``
+    resolves through :func:`repro.kernels.autotune.interpret_default`.
     """
     val_dtype = values.dtype
     if val_dtype.itemsize != 4:
         raise TypeError(f"radix payload must be a 32-bit dtype, "
                         f"got {val_dtype}")
-    kbits = _to_i32(key_to_sortable_bits(keys))
-    vbits = (values if val_dtype == jnp.int32
-             else jax.lax.bitcast_convert_type(values, jnp.int32))
-    out_k, out_v = _radix([kbits, vbits], bits, rows_per_step, interpret)
-    out_k = sortable_bits_to_key(_from_i32(out_k), keys.dtype)
+    words = keys if isinstance(keys, tuple) else (keys,)
+    planes = [_to_i32(key_to_sortable_bits(k)) for k in words]
+    planes.append(values if val_dtype == jnp.int32
+                  else jax.lax.bitcast_convert_type(values, jnp.int32))
+    for w in reversed(range(len(words))):
+        # the word sorted on goes first; the other planes ride along
+        rest = planes[:w] + planes[w + 1:]
+        out = _radix([planes[w]] + rest, bits, rows_per_step, interpret)
+        planes = out[1:w + 1] + [out[0]] + out[w + 1:]
+    out_k = [sortable_bits_to_key(_from_i32(p), k.dtype)
+             for p, k in zip(planes, words)]
+    out_v = planes[-1]
     if val_dtype != jnp.int32:
         out_v = jax.lax.bitcast_convert_type(out_v, val_dtype)
-    return out_k, out_v
+    return (tuple(out_k) if isinstance(keys, tuple) else out_k[0]), out_v
 
 
 def sort_segments_radix(keys: jnp.ndarray,

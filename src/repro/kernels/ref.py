@@ -73,15 +73,21 @@ def sort_segments_ref(keys: jnp.ndarray) -> jnp.ndarray:
     return jnp.sort(keys, axis=-1)
 
 
-def sort_kv_segments_ref(keys: jnp.ndarray, values: jnp.ndarray):
+def sort_kv_segments_ref(keys, values: jnp.ndarray):
     """Sort each segment of (key, value) rows by key (stable).
 
     Args:
-      keys:   (num_segments, segment_len)
+      keys:   (num_segments, segment_len), or a tuple of such word arrays,
+              most significant first: a compound key, ordered by its words
+              lexicographically (one multi-operand ``lax.sort``)
       values: (num_segments, segment_len) payload (e.g. record index)
     Returns:
-      (sorted_keys, permuted_values)
+      (sorted_keys, permuted_values), ``sorted_keys`` shaped as ``keys``
     """
+    if isinstance(keys, tuple):
+        out = jax.lax.sort(keys + (values,), dimension=-1, is_stable=True,
+                           num_keys=len(keys))
+        return tuple(out[:-1]), out[-1]
     order = jnp.argsort(keys, axis=-1, stable=True)
     skeys = jnp.take_along_axis(keys, order, axis=-1)
     svals = jnp.take_along_axis(values, order, axis=-1)

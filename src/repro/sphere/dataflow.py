@@ -71,12 +71,15 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 from repro.core.introspect import collective_counts, module_name, op_layers
 from repro.core.records import RecordCodec
-from repro.core.sort import uniform_bucket, uniform_splitters
+from repro.core.sort import (is_byte_key, key_layout, key_words,
+                             uniform_bucket, uniform_splitters,
+                             word_bucket_key)
 from repro.core.shuffle import ShufflePlan, record_hops
 from repro.kernels import autotune
 from repro.kernels import ops as kops
 from repro.obs import layers as obs_layers
-from repro.obs.layers import BUCKET, MOVE, EXCHANGE, SEGSORT, UDF
+from repro.kernels.radix_sort import sortable_bits_to_key
+from repro.obs.layers import BUCKET, KEY, MOVE, EXCHANGE, SEGSORT, UDF
 from repro.obs.metrics import REGISTRY
 from repro.obs.trace import NULL_TRACER
 
@@ -104,7 +107,7 @@ class ReduceStage:
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class SortStage:
-    key: Callable
+    key: Callable                         # -> (n,) int32, or (n, k) uint8 bytes
     splitters: Optional[Any] = None       # (num_buckets - 1,) int32 thresholds
     num_buckets: Optional[int] = None
     capacity_factor: float = 2.0
@@ -313,7 +316,8 @@ class _CacheEntry(NamedTuple):
     executable's HLO module name and ``layers`` its op->layer map
     (:func:`repro.core.introspect.op_layers`), read once per compile.
     ``bucketing`` names the range-bucketing path of each sort stage, in
-    stage order (:func:`bucketing`)."""
+    stage order (:func:`bucketing`), and ``sort_keys`` the key layout each
+    sorts (:func:`repro.core.sort.key_layout`)."""
 
     pipeline: "Dataflow"
     fn: Callable
@@ -323,6 +327,7 @@ class _CacheEntry(NamedTuple):
     module: str
     layers: Dict[str, str]
     bucketing: Tuple[str, ...]
+    sort_keys: Tuple[str, ...]
 
 
 def bucketing(stage: SortStage) -> str:
@@ -406,6 +411,9 @@ class SPMDExecutor:
         # mesh, so repeated runs reuse compiled sub-programs
         self._subflows: Dict[Tuple, Tuple[Dataflow, Dataflow]] = {}
         self._sub_execs: Dict[Any, "SPMDExecutor"] = {}
+        # the key layout of each sort stage, collected while a program is
+        # lowered (None outside a lowering)
+        self._sort_keys: Optional[List[str]] = None
 
     @property
     def axis_size(self) -> int:
@@ -503,7 +511,8 @@ class SPMDExecutor:
                 REGISTRY.counter("spmd.cache.hits").inc()
                 self._cache.move_to_end(key)
                 root.set(cache="hit")
-            obs_layers.ran(entry.module, entry.layers, entry.bucketing)
+            obs_layers.ran(entry.module, entry.layers, entry.bucketing,
+                           entry.sort_keys)
             with tr.span("spmd.execute", hops=len(entry.hops)):
                 out = entry.fn(*args)
                 if tr.enabled:
@@ -545,8 +554,13 @@ class SPMDExecutor:
         attrs = {"pipeline": pipeline.describe()} if tr.enabled else {}
         with tr.span("spmd.lower", **attrs):
             jitted = self.program(pipeline, with_carry=with_carry)
-            with record_hops(hops):
-                lowered = jitted.lower(*args)
+            self._sort_keys = []            # filled by _sort while tracing
+            try:
+                with record_hops(hops):
+                    lowered = jitted.lower(*args)
+                sort_keys = tuple(self._sort_keys)
+            finally:
+                self._sort_keys = None
         with tr.span("spmd.compile"):
             fn = lowered.compile()
             # the op->layer map of this executable, read once per compile
@@ -561,7 +575,8 @@ class SPMDExecutor:
         entry = _CacheEntry(
             pipeline=pipeline, fn=fn, has_sort=bool(sorts), hops=hops,
             collectives=collectives, module=module, layers=layers,
-            bucketing=tuple(bucketing(s) for s in sorts))
+            bucketing=tuple(bucketing(s) for s in sorts),
+            sort_keys=sort_keys)
         self._cache[key] = entry
         if len(self._cache) > self.cache_size:
             self._cache.popitem(last=False)
@@ -868,14 +883,24 @@ class SPMDExecutor:
         given are searched (``searchsorted``). Both give
         ``searchsorted(splitters, key, side="right")``.
 
+        Keys: the stage's ``key`` gives an int32 key (one word), or ``(n,
+        k)`` uint8 bytes ordered lexicographically (memcmp order, as
+        TeraSort's 10-byte keys): these become ``ceil(k / 4)`` big-endian
+        words (:func:`repro.core.sort.key_words`), each mapped to an int32
+        whose signed order is the word's unsigned order, and are bucketed
+        on their leading 31 bits (:func:`repro.core.sort.word_bucket_key`)
+        — monotone in the whole key, so the concatenated segments stay
+        sorted. Stage 2 sorts the word planes lexicographically.
+
         Layer scopes (:mod:`repro.obs.layers`): ``sphere.bucket`` holds the
-        splitters and both bucketings (send and regroup), ``sphere.segsort``
-        the segment sort and the sentinel keys feeding it, ``sphere.move``
-        the final gather; the exchange and partition/pack bring their own.
+        splitters and both bucketings (send and regroup), ``sphere.key`` a
+        byte key's conversion to words, ``sphere.segsort`` the segment sort
+        and the sentinel keys feeding it, ``sphere.move`` the final gather;
+        the exchange and partition/pack bring their own.
 
         Returns ``(records, valid, dropped, sentinel_hits)`` —
         ``sentinel_hits`` counts real received keys equal to the padding
-        sentinel (the key dtype's maximum, via
+        sentinel (the int32 maximum on every word, via
         :func:`repro.kernels.ops.pad_sentinel`), checked host-side by
         :meth:`run` when ``debug_checks``. The count is only taken when the
         resolved kernel is the *unstable* bitonic network — padding sits in
@@ -898,37 +923,43 @@ class SPMDExecutor:
             else:
                 def bucket_of(k):
                     return uniform_bucket(k, nb)
-            keys = jnp.asarray(stage.key(records)).astype(
-                jnp.int32).reshape(-1)
-            bucket = bucket_of(keys)
+            key = jnp.asarray(stage.key(records))
+            if self._sort_keys is not None:       # a program being lowered
+                self._sort_keys.append(key_layout(key))
+            planes, bkey = self._key_planes(key)
+            bucket = bucket_of(planes[0] if bkey is None else bkey)
         records, valid, dropped, plan = self._exchange(
             records, valid, bucket, nb, stage.capacity_factor, stage.chunks)
 
         # stage 2: bucket-major regroup (O(n) partition, stable) ...
         bpd = plan.buckets_per_device
         with jax.named_scope(SEGSORT):
-            keys = jnp.asarray(stage.key(records)).astype(
-                jnp.int32).reshape(-1)
-            sentinel = kops.pad_sentinel(keys.dtype)
-            skey = jnp.where(valid, keys, sentinel)
-            seg_cap = _segment_capacity(skey.shape[0], bpd,
+            planes, bkey = self._key_planes(stage.key(records))
+            sentinel = kops.pad_sentinel(planes[0].dtype)
+            skeys = [jnp.where(valid, p, sentinel) for p in planes]
+            seg_cap = _segment_capacity(skeys[0].shape[0], bpd,
                                         stage.capacity_factor)
             # resolve the stage-2 kernel now (trace-time): stability decides
             # whether sentinel-collision accounting is needed at all.
-            algo = kops.resolve_sort_algo(bpd, seg_cap, skey.dtype,
-                                          self.sort_algo, kv=True)
+            algo = kops.resolve_sort_algo(bpd, seg_cap, skeys[0].dtype,
+                                          self.sort_algo, kv=True,
+                                          words=len(planes))
             if autotune.is_stable(algo):
                 sentinel_hits = jnp.zeros((), jnp.int32)
             else:
+                hit = valid
+                for p in planes:
+                    hit = hit & (p == sentinel)
                 sentinel_hits = jax.lax.psum(
-                    jnp.sum((valid & (keys == sentinel)).astype(jnp.int32)),
-                    plan.pmean_axes())
+                    jnp.sum(hit.astype(jnp.int32)), plan.pmean_axes())
         with jax.named_scope(BUCKET):
-            local = bucket_of(skey) - plan.device_index() * bpd
+            local = (bucket_of(skeys[0] if bkey is None else bkey)
+                     - plan.device_index() * bpd)
             seg_dest = jnp.where(valid, local, bpd)   # invalid -> overflow
         leaves, treedef = jax.tree.flatten(records)
+        w = len(planes)
         tiles, in_rng, _, seg_drop = kops.partition_pack(
-            [skey] + leaves, seg_dest, bpd, seg_cap,
+            skeys + leaves, seg_dest, bpd, seg_cap,
             use_pallas=self.use_pallas)
         with jax.named_scope(MOVE):
             dropped += jax.lax.psum(seg_drop, plan.pmean_axes())
@@ -938,17 +969,35 @@ class SPMDExecutor:
         # in its prefix — exactly where ``in_rng`` already points (pads sit
         # in the suffix, which stable kernels preserve even on key ties).
         with jax.named_scope(SEGSORT):
-            seg_keys = jnp.where(in_rng, tiles[0], sentinel)
+            seg_keys = tuple(jnp.where(in_rng, t, sentinel)
+                             for t in tiles[:w])
             pos = jnp.arange(bpd * seg_cap, dtype=jnp.int32).reshape(
                 bpd, seg_cap)
-            _, order = kops.sort_kv_segments(seg_keys, pos, algo=algo)
+            # one word is sorted as a plain key array, as int32 keys were
+            _, order = kops.sort_kv_segments(
+                seg_keys if w > 1 else seg_keys[0], pos, algo=algo)
             order = order.reshape(-1)
         with jax.named_scope(MOVE):
             records = jax.tree.unflatten(treedef, [
                 jnp.take(t.reshape((bpd * seg_cap,) + t.shape[2:]), order,
                          axis=0)
-                for t in tiles[1:]])
+                for t in tiles[w:]])
         return records, in_rng.reshape(-1), dropped, sentinel_hits
+
+    @staticmethod
+    def _key_planes(key):
+        """The int32 planes a sort key is ordered by, most significant
+        first, and the int32 it is bucketed on (None: the first plane).
+        An int32 key is one plane; a byte key's words (in ``sphere.key``)
+        become planes whose signed order is the words' unsigned order, and
+        it is bucketed on its leading 31 bits."""
+        key = jnp.asarray(key)
+        if not is_byte_key(key):
+            return (key.astype(jnp.int32).reshape(-1),), None
+        with jax.named_scope(KEY):
+            words = key_words(key)
+            planes = tuple(sortable_bits_to_key(w, jnp.int32) for w in words)
+            return planes, word_bucket_key(words[0])
 
 
 # -- host (Sector/SPE) executor ----------------------------------------------
@@ -1179,7 +1228,8 @@ class HostExecutor:
             if pending_sort is not None:
                 # stage 2 of a sort: this segment IS one range partition
                 key = np.asarray(pending_sort.key(records))
-                order = np.argsort(key, kind="stable")
+                order = (np.lexsort(key_words(key)[::-1]) if is_byte_key(key)
+                         else np.argsort(key, kind="stable"))
                 records = jax.tree.map(lambda a: a[order], records)
             valid = np.ones((_leading(records),), bool)
             for stage in phase.stages:
@@ -1207,7 +1257,9 @@ class HostExecutor:
             if term is None:
                 return packed
             if isinstance(term, SortStage):
-                keys = np.asarray(term.key(records)).astype(np.int32)
+                keys = np.asarray(term.key(records))
+                keys = (word_bucket_key(key_words(keys)[0])
+                        if is_byte_key(keys) else keys.astype(np.int32))
                 spl = (np.asarray(term.splitters) if term.splitters is not None
                        else uniform_splitters(nb))
                 ids = np.searchsorted(spl, keys, side="right")

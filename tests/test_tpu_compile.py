@@ -71,15 +71,21 @@ def _kernel_case(name, ts, chip):
     if name == "partition_regroup":
         return (lambda d: partition_rank_pallas(d, ts.bpd, interpret=False),
                 (arg((ts.recv,)),))
+    algo, _, words = name.partition("_key")
     kernel = {"bitonic": sort_kv_segments_pallas,
-              "radix": sort_kv_segments_radix}[name]
+              "radix": sort_kv_segments_radix}[algo]
     shape = (ts.bpd, ts.seg_cap)
+    if words:
+        # a compound key of ``words`` int32 word planes (a 10-byte key: 3)
+        return (lambda *kv: kernel(kv[:-1], kv[-1], interpret=False),
+                tuple(arg(shape) for _ in range(int(words) + 1)))
     return (lambda k, v: kernel(k, v, interpret=False),
             (arg(shape), arg(shape)))
 
 
 @pytest.mark.parametrize("name", ["partition_send", "partition_regroup",
-                                  "bitonic", "radix"])
+                                  "bitonic", "radix", "bitonic_key3",
+                                  "radix_key3"])
 def test_kernel_compiles_at_terasort_cell(name, topo, one_chip_terasort):
     fn, args = _kernel_case(name, one_chip_terasort,
                             SingleDeviceSharding(topo.devices[0]))
